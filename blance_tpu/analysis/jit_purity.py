@@ -75,9 +75,8 @@ _MUTATING_METHODS = {
 
 _COERCIONS = {"float", "int", "bool"}
 
-# Callables that wrap a function for tracing.  Matching is by resolved
-# dotted suffix so both ``jax.experimental.shard_map.shard_map`` and a
-# local ``_shard_map`` shim qualify.
+# Callables that wrap a function for tracing, matched by the leaf of
+# the resolved dotted name (``jax.shard_map``).
 _TRACE_WRAPPER_SUFFIXES = ("shard_map",)
 
 
@@ -162,11 +161,7 @@ class JitPurityPass:
         if dotted is None:
             return False
         fq = self.index.resolve(mi, dotted)
-        # lstrip("_"): version-portability shims are conventionally the
-        # wrapped name with a leading underscore (parallel/sharded.py's
-        # ``_shard_map``).
-        leaf = fq.split(".")[-1].lstrip("_")
-        return any(leaf == s for s in _TRACE_WRAPPER_SUFFIXES)
+        return fq.split(".")[-1] in _TRACE_WRAPPER_SUFFIXES
 
     def _mark_root(self, mi: ModuleInfo, func_ref: ast.AST,
                    statics: set[str], aliases: dict[str, str]) -> None:
@@ -299,7 +294,7 @@ class JitPurityPass:
                     for arg in list(node.args) + \
                             [kw.value for kw in node.keywords]:
                         self._mark_root(mi, arg, set(), aliases)
-                # partial(_shard_map, body, ...): treat as a wrapper call
+                # partial(jax.shard_map, body, ...): a wrapper call too
                 dotted = _dotted(node.func)
                 if dotted is not None and \
                         self.index.resolve(mi, dotted) == "functools.partial" \

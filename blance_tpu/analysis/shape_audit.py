@@ -209,8 +209,6 @@ def _build_sharded(d: Dims):
     from ..parallel.sharded import (
         PARTITION_AXIS,
         SOLVER_IN_LAYOUT,
-        _build_checked,
-        _shard_map,
         layout_specs,
         make_mesh,
     )
@@ -222,13 +220,10 @@ def _build_sharded(d: Dims):
     shard = PartitionSpec(PARTITION_AXIS)
     body = partial(solve_dense, constraints=d.constraints, rules=d.rules,
                    axis_name=PARTITION_AXIS, fused_score="off")
-    sm = partial(_shard_map, body, mesh=mesh,
-                 in_specs=layout_specs(SOLVER_IN_LAYOUT),
-                 out_specs=shard)
-    # Same replication-checker policy as solve_dense_sharded: pre-vma
-    # JAX has no replication rule for the auction while_loop.
-    has_vma = hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-    fn = _build_checked(sm, has_vma)
+    # Same varying-axes checker policy as solve_dense_sharded.
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=layout_specs(SOLVER_IN_LAYOUT),
+                       out_specs=shard, check_vma=True)
     return fn, _solver_args(d, jnp), {}
 
 
@@ -318,8 +313,6 @@ def _build_pipeline_sharded(d: Dims, warm: bool = False):
         PIPELINE_WARM_OUT_LAYOUT,
         SOLVER_IN_LAYOUT,
         WARM_EXTRA_LAYOUT,
-        _build_checked,
-        _shard_map,
         layout_specs,
         make_mesh,
     )
@@ -343,10 +336,9 @@ def _build_pipeline_sharded(d: Dims, warm: bool = False):
         in_layout = SOLVER_IN_LAYOUT
         out_layout = PIPELINE_COLD_OUT_LAYOUT
         extra = ()
-    sm = partial(_shard_map, body, mesh=mesh,
-                 in_specs=layout_specs(in_layout),
-                 out_specs=layout_specs(out_layout))
-    fn = _build_checked(sm, False)  # checker off: psum'd replicated outs
+    # Checker off: psum'd replicated outs.
+    fn = jax.shard_map(body, mesh=mesh, in_specs=layout_specs(in_layout),
+                       out_specs=layout_specs(out_layout), check_vma=False)
     return fn, _solver_args(d, jnp) + extra, {}
 
 
@@ -414,8 +406,6 @@ def _build_sparse_sharded(d: Dims):
         SOLVER_IN_LAYOUT,
         SPARSE_COLD_OUT_LAYOUT,
         SPARSE_EXTRA_LAYOUT,
-        _build_checked,
-        _shard_map,
         layout_specs,
         make_mesh,
     )
@@ -428,12 +418,11 @@ def _build_sparse_sharded(d: Dims):
                    constraints=d.constraints, rules=d.rules,
                    axis_name=PARTITION_AXIS, max_iterations=4,
                    sparse_impl="xla")
-    sm = partial(_shard_map, body, mesh=mesh,
-                 in_specs=layout_specs(SOLVER_IN_LAYOUT
-                                       + SPARSE_EXTRA_LAYOUT),
-                 out_specs=layout_specs(SPARSE_COLD_OUT_LAYOUT))
-    has_vma = hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-    fn = _build_checked(sm, has_vma)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=layout_specs(SOLVER_IN_LAYOUT
+                                             + SPARSE_EXTRA_LAYOUT),
+                       out_specs=layout_specs(SPARSE_COLD_OUT_LAYOUT),
+                       check_vma=True)
     return fn, _solver_args(d, None) + (
         _sds((d.P, _sparse_k(d)), np.int32),), {}
 

@@ -40,8 +40,9 @@ def get() -> object:
     ext = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
     so = os.path.join(_build_dir(), "_blance_marshal" + ext)
     include = sysconfig.get_paths()["include"]
-    if not compile_cached(src, so, ["gcc", "-O2", "-shared", "-fPIC",
-                                    f"-I{include}", "-o", so, src]):
+    so = compile_cached(src, so, ["gcc", "-O2", "-shared", "-fPIC",
+                                  f"-I{include}", "-o", so, src])
+    if so is None:
         _FAILED = True
         return None
     try:

@@ -42,12 +42,6 @@ from ._tiles import tile_env
 _TILE_P = tile_env("BLANCE_FUSED_TILE_P", 256, 8)
 _TILE_N = tile_env("BLANCE_FUSED_TILE_N", 2048, 128)
 
-try:  # ``vma`` on ShapeDtypeStruct arrived with JAX's varying-axes model
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _SDS_HAS_VMA = True
-except TypeError:
-    _SDS_HAS_VMA = False
-
 __all__ = ["fused_score_min2", "ScoreInputs", "pack_score_inputs",
            "score_at_columns", "jitter_hash"]
 
@@ -283,15 +277,12 @@ def fused_score_min2(
     t_width = si.taken.shape[1]
     a_width = si.present.shape[1]
 
-    # Pre-vma JAX has no varying-axes checker (and no ``vma`` kwarg on
-    # ShapeDtypeStruct); those runtimes use check_rep=False instead, so
-    # the annotation is simply not needed there.
-    sds_kw = {"vma": frozenset(vma)} if vma and _SDS_HAS_VMA else {}
+    vma_set = frozenset(vma)
     out_shape = [
-        jax.ShapeDtypeStruct((p, 1), jnp.float32, **sds_kw),  # best
-        jax.ShapeDtypeStruct((p, 1), jnp.int32, **sds_kw),    # idx (local)
-        jax.ShapeDtypeStruct((p, 1), jnp.float32, **sds_kw),  # second
-        jax.ShapeDtypeStruct((p, 1), jnp.float32, **sds_kw),  # raw at idx
+        jax.ShapeDtypeStruct((p, 1), jnp.float32, vma=vma_set),  # best
+        jax.ShapeDtypeStruct((p, 1), jnp.int32, vma=vma_set),  # idx (local)
+        jax.ShapeDtypeStruct((p, 1), jnp.float32, vma=vma_set),  # second
+        jax.ShapeDtypeStruct((p, 1), jnp.float32, vma=vma_set),  # raw at idx
     ]
     out_spec = pl.BlockSpec((tp, 1), lambda i, j: (i, 0))
     row1 = pl.BlockSpec((1, tn), lambda i, j: (0, j))

@@ -50,14 +50,6 @@ from ..plan.tensor import (
 from ..obs import device as _obs_device
 from ..obs import get_recorder
 
-# shard_map moved across JAX versions (jax.experimental.shard_map ->
-# top-level jax.shard_map); resolve once so the pinned CI versions and
-# newer runtimes both work.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax (e.g. 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["make_mesh", "make_mesh_2d", "make_hybrid_mesh",
            "make_mesh_auto", "mesh_shape_for", "slice_major_order",
            "solve_dense_sharded", "solve_pipeline_sharded",
@@ -318,26 +310,6 @@ def pad_nodes(arr: np.ndarray, multiple: int,
     return pad_to(arr, arr.ndim - 1, n + (-n) % multiple, fill)
 
 
-def _build_checked(sm, checked_ok: bool):
-    """Build a shard_map'd fn, disabling the replication/vma checker
-    when the body's collectives confuse it (see solve_dense_sharded).
-
-    The disable kwarg has been renamed across JAX versions (check_vma
-    today, check_rep before); probe by retrying rather than inspecting,
-    so a version exposing neither still builds (and then simply runs
-    with the checker on)."""
-    if checked_ok:
-        return sm()
-    for kwargs in ({"check_vma": False}, {"check_rep": False}):
-        try:
-            return sm(**kwargs)
-        except TypeError:
-            continue
-    # Neither kwarg exists: build with the checker on, outside the try
-    # so a genuine shard_map TypeError propagates un-swallowed.
-    return sm()
-
-
 def solve_dense_sharded(
     mesh: Mesh,
     prev: np.ndarray,
@@ -424,11 +396,9 @@ def solve_dense_sharded(
 
     shard = P(PARTITION_AXIS)
     rep = P()
-    # Pre-vma JAX (the check_rep model: no lax.pcast/pvary) has no
-    # replication rule for while_loop, so the checker must be off on ANY
-    # mesh there; vma-era JAX keeps it on for the plain 1-D matrix path.
-    # Off the 1-D matrix path: the output is node-replicated by
-    # construction — every node shard derives identical assignments from
+    # The varying-axes checker stays on for the plain 1-D matrix path.
+    # Off it: the output is node-replicated by construction — every
+    # node shard derives identical assignments from
     # the all_gathered stats, a property tests/test_sharded_2d.py proves
     # empirically (solves are bit-identical across node-shard counts) —
     # but the varying-axes checker can't see through the all_gather/psum
@@ -437,8 +407,7 @@ def solve_dense_sharded(
     # inside pallas_call rejects the kernel's mix of node-replicated [N]
     # tables and partition-varying columns (its outputs carry correct
     # vma annotations; the per-op walk is what can't see through).
-    has_vma = hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-    checked_ok = has_vma and not node_axis and fused_score == "off"
+    checked_ok = not node_axis and fused_score == "off"
     device_put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
 
     dev_args = (
@@ -470,11 +439,11 @@ def solve_dense_sharded(
             constraints=constraints, rules=rules,
             axis_name=PARTITION_AXIS, node_axis=node_axis,
             node_shards=node_shards, fused_score=fused_score)
-        sm_w = partial(_shard_map, body_w, mesh=mesh,
-                       in_specs=layout_specs(
-                           SOLVER_IN_LAYOUT + WARM_EXTRA_LAYOUT),
-                       out_specs=(shard, rep, rep))
-        fn_w = _build_checked(sm_w, checked_ok)
+        fn_w = jax.shard_map(body_w, mesh=mesh,
+                             in_specs=layout_specs(
+                                 SOLVER_IN_LAYOUT + WARM_EXTRA_LAYOUT),
+                             out_specs=(shard, rep, rep),
+                             check_vma=checked_ok)
         with rec.span("plan.solve.attempt", warm=True, sharded=True), \
                 _obs_device.entry("sharded.warm"):
             # transfer_guard allowlist: dispatching a fresh shard_map
@@ -517,10 +486,9 @@ def solve_dense_sharded(
         node_shards=node_shards,
         fused_score=fused_score,
     )
-    sm = partial(_shard_map, body, mesh=mesh,
-                 in_specs=layout_specs(SOLVER_IN_LAYOUT),
-                 out_specs=shard)
-    fn = _build_checked(sm, checked_ok)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=layout_specs(SOLVER_IN_LAYOUT),
+                       out_specs=shard, check_vma=checked_ok)
     # Same dispatch-time constant-upload exemption as the warm path.
     # The observatory attribution is first-wins, so the body's inner
     # solve_dense_converged labels stay subordinate to "sharded.cold".
@@ -603,14 +571,13 @@ def solve_sparse_sharded(
 
     shard = P(PARTITION_AXIS)
     rep = P()
-    has_vma = hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
     # The pallas sparse2 kernel needs the checker off for the same
     # reason as the fused dense engine on any mesh (see
     # solve_dense_sharded): the per-op vma propagation inside
     # pallas_call rejects the kernel's mix of node-replicated tables
     # and partition-varying columns, even though its outputs carry
     # correct annotations.
-    checked_ok = has_vma and impl != "pallas"
+    checked_ok = impl != "pallas"
     device_put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
     dev_args = (
         device_put(jnp.asarray(prev_p), shard),
@@ -652,12 +619,12 @@ def solve_sparse_sharded(
         sparse_body_w = partial(
             _warm_repair_sparse, constraints=constraints, rules=rules,
             axis_name=PARTITION_AXIS, sparse_impl=impl)
-        sm_w = partial(
-            _shard_map, sparse_body_w, mesh=mesh,
+        fn_w = jax.shard_map(
+            sparse_body_w, mesh=mesh,
             in_specs=layout_specs(SOLVER_IN_LAYOUT + SPARSE_EXTRA_LAYOUT
                                   + WARM_EXTRA_LAYOUT),
-            out_specs=layout_specs(SPARSE_WARM_OUT_LAYOUT))
-        fn_w = _build_checked(sm_w, checked_ok)
+            out_specs=layout_specs(SPARSE_WARM_OUT_LAYOUT),
+            check_vma=checked_ok)
         with rec.span("plan.solve.attempt", warm=True, sharded=True,
                       engine="sparse"), \
                 _obs_device.entry("sparse.sharded.warm"):
@@ -682,11 +649,11 @@ def solve_sparse_sharded(
         _solve_sparse_converged_impl, constraints=constraints,
         rules=rules, axis_name=PARTITION_AXIS,
         max_iterations=max_iterations, sparse_impl=impl)
-    sm = partial(
-        _shard_map, sparse_body, mesh=mesh,
+    fn = jax.shard_map(
+        sparse_body, mesh=mesh,
         in_specs=layout_specs(SOLVER_IN_LAYOUT + SPARSE_EXTRA_LAYOUT),
-        out_specs=layout_specs(SPARSE_COLD_OUT_LAYOUT))
-    fn = _build_checked(sm, checked_ok)
+        out_specs=layout_specs(SPARSE_COLD_OUT_LAYOUT),
+        check_vma=checked_ok)
     with rec.span("plan.solve.attempt", sharded=True, engine="sparse"), \
             jax.transfer_guard("allow"), \
             _obs_device.entry("sparse.sharded.cold"):
@@ -732,10 +699,10 @@ def _pipeline_sharded_fn(
             fused_score=fused_score, favor_min_nodes=favor_min_nodes)
         in_layout = SOLVER_IN_LAYOUT
         out_layout = PIPELINE_COLD_OUT_LAYOUT
-    sm = partial(_shard_map, pipe_body, mesh=mesh,
-                 in_specs=layout_specs(in_layout),
-                 out_specs=layout_specs(out_layout))
-    return jax.jit(_build_checked(sm, False))
+    return jax.jit(jax.shard_map(pipe_body, mesh=mesh,
+                                 in_specs=layout_specs(in_layout),
+                                 out_specs=layout_specs(out_layout),
+                                 check_vma=False))
 
 
 def solve_pipeline_sharded(

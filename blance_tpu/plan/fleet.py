@@ -298,11 +298,9 @@ def _mesh_callable(mesh, warm: bool, constraints: Constraints, rules: Rules,
 
     Tenant solves are independent — no collectives ride the mesh axis —
     so in/out specs shard every operand's leading (batch) dimension and
-    nothing is replicated.  The replication checker is disabled the same
-    way parallel/sharded.py does for while-loop bodies (pre-vma JAX has
-    no replication rule for while; nothing here is replicated anyway).
+    nothing is replicated, so the varying-axes checker has nothing to
+    verify and stays off.
     """
-    from ..parallel.sharded import _build_checked, _shard_map
     from jax.sharding import PartitionSpec
 
     key = (mesh, warm, constraints, rules, max_iterations, fused_score)
@@ -326,9 +324,8 @@ def _mesh_callable(mesh, warm: bool, constraints: Constraints, rules: Rules,
                        rules=rules, max_iterations=max_iterations,
                        fused_score=fused_score)
         n_in = 8
-    sm = partial(_shard_map, body, mesh=mesh, in_specs=(sh,) * n_in,
-                 out_specs=(sh, sh, sh))
-    fn = jax.jit(_build_checked(sm, False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(sh,) * n_in,
+                               out_specs=(sh, sh, sh), check_vma=False))
     while len(_MESH_FN_CACHE) >= _MESH_FN_CACHE_MAX:
         # Evict the least-recently-used wrapper only — clearing the
         # whole table would force every hot class to retrace.

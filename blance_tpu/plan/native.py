@@ -73,8 +73,9 @@ def _load_lib() -> Optional[ctypes.CDLL]:
         return _LIB
     src = _source_path()
     so = os.path.join(_build_dir(), "_native_planner.so")
-    if not compile_cached(src, so, ["g++", "-O3", "-shared", "-fPIC",
-                                    "-std=c++17", "-o", so, src]):
+    so = compile_cached(src, so, ["g++", "-O3", "-shared", "-fPIC",
+                                  "-std=c++17", "-o", so, src])
+    if so is None:
         _LIB_FAILED = True
         return None
     try:

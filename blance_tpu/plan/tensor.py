@@ -139,21 +139,19 @@ def set_fused_score_default(mode: str) -> None:
 
 
 def _device_hbm_bytes() -> int:
-    """Accelerator memory per chip; 16 GiB (v5e) when the runtime does
-    not report a limit (e.g. CPU test meshes)."""
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except (RuntimeError, IndexError, AttributeError, TypeError,
-            ValueError):
-        # The documented "runtime does not report" shapes: backend init
-        # failure, no devices, a backend without memory_stats, or a
-        # stats dict with a non-numeric limit.  Anything else (bugs,
-        # KeyboardInterrupt) propagates.
-        pass
-    return 16 * 2 ** 30
+    """Accelerator memory per chip, as the runtime reports it.  The CPU
+    platform reports no limit, and stands in for a 16 GiB (v5e) chip so
+    CPU test meshes make the engine choices the chip would; any other
+    device that reports no limit is an error, not a guess."""
+    dev = jax.devices()[0]
+    limit = int((dev.memory_stats() or {}).get("bytes_limit", 0))
+    if limit > 0:
+        return limit
+    if dev.platform == "cpu":
+        return 16 * 2 ** 30
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no memory "
+        f"limit (memory_stats()['bytes_limit']); the engine choice needs it")
 
 
 def resolve_fused_score(mode: str, p: int, n: int) -> str:
@@ -650,12 +648,14 @@ def _combine_min2(
     return take(bests), take(choices), second, take(raws)
 
 
-def _axis_size(axis_name: str):
-    """``lax.axis_size`` appeared in newer JAX; ``psum(1, axis)`` is the
-    long-standing equivalent on older pins (e.g. 0.4.x)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+def _ensure_varying(x: jnp.ndarray, *axes: Optional[str]) -> jnp.ndarray:
+    """Mark ``x`` varying over each named mesh axis it does not already
+    vary over.  Under shard_map, values created inside the body (zeros,
+    constants) are axis-invariant, so loop carries and cond branches
+    that mix them with shard-local values must be cast up front for
+    their varying-axes types to agree."""
+    missing = tuple(a for a in axes if a and a not in jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def _shard_capacity(cap: jnp.ndarray, axis_name: Optional[str]) -> jnp.ndarray:
@@ -667,7 +667,7 @@ def _shard_capacity(cap: jnp.ndarray, axis_name: Optional[str]) -> jnp.ndarray:
     """
     if not axis_name:
         return cap
-    ns = _axis_size(axis_name)
+    ns = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     base_cap = jnp.floor(cap / ns)
     rem = cap - base_cap * ns
@@ -784,7 +784,7 @@ def _pin_prev_holders(
         # node's weight units — whichever is larger.  The lmin band is
         # divided by the shard count like the quota: it is a GLOBAL
         # allowance, and each shard orders only its own holders.
-        ns = _axis_size(axis_name) if axis_name else 1
+        ns = lax.axis_size(axis_name) if axis_name else 1
         nclip = jnp.clip(node_s, 0, n - 1)
         band = (lmin + slack[perm]) * div[nclip] / ns
         cap_here = jnp.maximum(cap_quota[nclip], band)
@@ -1057,26 +1057,9 @@ def _assign_slot(
         jnp.array(True),
         jnp.array(0, jnp.int32),
     )
-    for ax in (axis_name, node_axis):
-        if not ax:
-            continue
-        # Freshly-created carries are axis-invariant until the (shard-local)
-        # loop body makes them varying; mark them varying up front so carry
-        # types agree.  Skip values that are already varying.  Pre-vma JAX
-        # (the check_rep model: no pcast/pvary) has no varying-axes types
-        # to reconcile, so there is nothing to mark.
-        if hasattr(lax, "pcast"):
-            _to_varying = lambda x: lax.pcast(x, (ax,), to="varying")
-        elif hasattr(lax, "pvary"):
-            _to_varying = lambda x: lax.pvary(x, (ax,))
-        else:
-            continue
-        _typeof = jax.typeof if hasattr(jax, "typeof") else jax.core.get_aval
-
-        def ensure_varying(x):
-            vma = getattr(_typeof(x), "vma", frozenset())
-            return x if ax in vma else _to_varying(x)
-        init = tuple(ensure_varying(x) for x in init)
+    # Freshly-created carries are axis-invariant until the (shard-local)
+    # loop body makes them varying.
+    init = tuple(_ensure_varying(x, axis_name, node_axis) for x in init)
     slot_assign, unassigned, _rem, used, _, _ = lax.while_loop(
         round_cond, round_body, init)
 
@@ -1636,11 +1619,17 @@ def _solve_assign(
             def keep_pins(_):
                 return init_assign, pin_used, jnp.zeros(p, jnp.bool_)
 
+            def varying(branch):
+                # Both branches' outputs must carry the same varying-axes
+                # types; keep_pins' fresh zeros are partition-invariant.
+                return lambda op: tuple(
+                    _ensure_varying(x, axis_name) for x in branch(op))
+
             # NB: no collectives run inside either branch (_assign_slot is
             # shard-local by design), so a cond on the globally-agreed
             # all_pinned flag is safe under shard_map.
             slot_assign, used, exh_slot = lax.cond(
-                all_pinned, keep_pins, run_auction, None)
+                all_pinned, varying(keep_pins), varying(run_auction), None)
             exhausted = exhausted | exh_slot
             used = _psum(used, axis_name)  # global per-node accepted weight
 
@@ -2052,7 +2041,7 @@ def _repair_ok(prev, out, new_used, carry_used, dirty, pweights, nweights,
     cap_w = jnp.where(valid & (nweights >= 0), jnp.maximum(nweights, 1.0),
                       0.0)
     cap_share = cap_w / jnp.maximum(jnp.sum(cap_w), 1.0)
-    ns = _axis_size(axis_name) if axis_name else 1
+    ns = lax.axis_size(axis_name) if axis_name else 1
     max_w = jnp.max(pweights) if p else jnp.float32(0.0)
     if axis_name:
         max_w = lax.pmax(max_w, axis_name)

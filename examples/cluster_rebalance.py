@@ -19,14 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    # Some TPU runtime plugins override JAX_PLATFORMS from the
-    # environment; pin through the config API so the documented
-    # "set JAX_PLATFORMS=cpu" invocation is honored everywhere.
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import blance_tpu as bt
 from blance_tpu.orchestrate import OrchestratorOptions, orchestrate_moves
 

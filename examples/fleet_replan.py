@@ -23,14 +23,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    # Some TPU runtime plugins override JAX_PLATFORMS from the
-    # environment; pin through the config API so the documented
-    # "use JAX_PLATFORMS=cpu" invocation is honored everywhere.
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import jax

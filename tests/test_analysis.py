@@ -256,14 +256,14 @@ def test_jit_root_via_shard_map_wrapper(tmp_path):
     fs = _jit_findings(tmp_path, """
         from functools import partial
         import time
-        from jax.experimental.shard_map import shard_map as _shard_map
+        import jax
 
         def body(x):
             return x + time.time()
 
         def build(mesh, spec):
-            fn = _shard_map(partial(body), mesh=mesh,
-                            in_specs=spec, out_specs=spec)
+            fn = jax.shard_map(partial(body), mesh=mesh,
+                               in_specs=spec, out_specs=spec)
             return fn
     """)
     assert _rules(fs) == ["JIT001"]

@@ -905,8 +905,8 @@ def test_hier_audit_counts_planted_miss():
 def test_engine_compile_failure_falls_back_to_fused(monkeypatch):
     """An auto-selected matrix engine that dies in compile must retry on
     the fused engine with a UserWarning and a timer annotation — never a
-    user-visible error (VERDICT r4 #6: the production mirror of
-    bench.py's degradation path)."""
+    user-visible error (the production mirror of bench.py's engine
+    degradation)."""
     import warnings
 
     from blance_tpu.plan import tensor
