@@ -1,0 +1,8 @@
+"""Mean per event of the program's `plan.validate` phase, in ms (the
+PhaseTimer that plan_pipeline's `timer=` fills)."""
+
+from benchmark.metrics._phase import per_event_ms
+
+
+def read(ctx):
+    return per_event_ms(ctx, "validate")

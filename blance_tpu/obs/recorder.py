@@ -31,11 +31,14 @@ only by attached sinks (``blance_tpu.obs.sinks``); an un-sinked recorder
 in a long-running service never grows with traffic.
 
 Timestamps come from the recorder's injectable ``clock`` (default
-``time.perf_counter``) in seconds, offset against the recorder's
-construction time (``t0``) at export — one consistent monotonic clock
-for every span in a process, which is what lets the Chrome-trace
-exporter lay host spans on a single timeline next to ``device_profile``
-TPU traces captured over the same interval.  Injecting the clock is
+``utils.hostclock.perf_now``, the host perf-clock seam:
+``time.perf_counter`` unless a test injects another) in seconds, offset
+against the recorder's construction time (``t0``) at export — one
+consistent monotonic clock for every span in a process, which is what
+lets the Chrome-trace exporter lay host spans on a single timeline next
+to ``device_profile`` TPU traces captured over the same interval (phase
+spans, ``phase_span``, are also written into such a trace while its
+session is active).  Injecting the clock is
 what makes telemetry DETERMINISTIC under the controlled virtual-time
 loop (``testing.sched.DeterministicLoop``): ``Recorder(clock=loop.time)``
 makes every span duration, SLO gauge, and exposition snapshot a pure
@@ -49,10 +52,12 @@ import contextlib
 import contextvars
 import itertools
 import math
+import sys
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Callable, Iterator, Optional
+
+from ..utils.hostclock import perf_now
 
 if TYPE_CHECKING:  # annotation-only
     from ..utils.trace import PhaseTimer
@@ -163,7 +168,7 @@ class Recorder:
     durations, SLO gauges, exposition snapshots — under virtual time."""
 
     def __init__(self, sinks: tuple = (),
-                 clock: Callable[[], float] = time.perf_counter) -> None:
+                 clock: Callable[[], float] = perf_now) -> None:
         self._clock = clock
         self.t0 = clock()
         self.sinks: list = list(sinks)
@@ -430,6 +435,19 @@ def use_recorder(recorder: Recorder) -> Iterator[Recorder]:
         set_recorder(prev)
 
 
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _trace_annotation(name: str) -> contextlib.AbstractContextManager:
+    """``jax.profiler.TraceAnnotation(name)`` while a profiler session is
+    active, else a no-op.  jax is looked up, never imported: a process
+    that has not loaded it cannot have a session open."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name)
+
+
 @contextlib.contextmanager
 def phase_span(name: str, timer: Optional["PhaseTimer"] = None,
                phase: Optional[str] = None,
@@ -439,13 +457,18 @@ def phase_span(name: str, timer: Optional["PhaseTimer"] = None,
     The instrumented pipeline names spans hierarchically ("plan.encode")
     while PhaseTimer callers keep their short phase keys ("encode", the
     default: the last dot segment) — one timed region, two views, no
-    double-recorded span."""
-    rec = get_recorder()
-    start = rec.now()
+    double-recorded span.  The timer adds the span's own duration, so
+    both views measure one interval.  While a profiler session is active
+    the region is also a host annotation of the same name in the
+    profiler's trace, on the trace's own clock (``Recorder.span`` and
+    ``record_span`` are not: orchestrator spans interleave across asyncio
+    tasks on one thread, which a thread's trace events cannot)."""
+    sp: Optional[Span] = None
     try:
-        with rec.span(name, **attrs) as sp:
+        with get_recorder().span(name, **attrs) as sp, \
+                _trace_annotation(name):
             yield sp
     finally:
-        if timer is not None:
+        if timer is not None and sp is not None:
             timer._accumulate(phase or name.rsplit(".", 1)[-1],
-                              rec.now() - start)
+                              sp.duration_s)

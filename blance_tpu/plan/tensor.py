@@ -2840,8 +2840,9 @@ def plan_pipeline(
         return next_map, warnings, moves
     del nodes_to_add
 
-    with rec.span("plan.pipeline", partitions=len(partitions_to_assign),
-                  nodes=len(nodes_all)):
+    with phase_span("plan.pipeline", timer=timer,
+                    partitions=len(partitions_to_assign),
+                    nodes=len(nodes_all)):
         rec.count("plan.pipeline.calls")
         with phase_span("plan.encode", timer=timer):
             problem = encode_problem(
@@ -2855,33 +2856,36 @@ def plan_pipeline(
                 partitions_to_assign, nodes_to_remove)
             return next_map, warnings, {n: [] for n in problem.partitions}
 
-        rules = tuple(
-            tuple(problem.rules.get(si, ())) for si in range(problem.S))
-        constraints = tuple(int(c) for c in problem.constraints)
+        with phase_span("plan.pipeline.prepare", timer=timer):
+            rules = tuple(
+                tuple(problem.rules.get(si, ())) for si in range(problem.S))
+            constraints = tuple(int(c) for c in problem.constraints)
 
-        prev_a = problem.prev
-        pw_a = problem.partition_weights
-        nw_a = problem.node_weights
-        valid_a = problem.valid_node
-        stick_a = problem.stickiness
-        gids_a = problem.gids
-        gv_a = problem.gid_valid
-        solve_p, solve_n = problem.P, problem.N
-        if opts.shape_bucketing:
-            from ..core.encode import bucket_size, pad_problem_arrays
+            prev_a = problem.prev
+            pw_a = problem.partition_weights
+            nw_a = problem.node_weights
+            valid_a = problem.valid_node
+            stick_a = problem.stickiness
+            gids_a = problem.gids
+            gv_a = problem.gid_valid
+            solve_p, solve_n = problem.P, problem.N
+            if opts.shape_bucketing:
+                from ..core.encode import bucket_size, pad_problem_arrays
 
-            solve_p = bucket_size(problem.P)
-            solve_n = bucket_size(problem.N)
-            (prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a) = \
-                pad_problem_arrays(prev_a, pw_a, nw_a, valid_a, stick_a,
-                                   gids_a, gv_a, solve_p, solve_n)
-        _check_tier_band_scale(prev_a, pw_a, nw_a, valid_a, stick_a,
-                               constraints, rules)
-        mode = resolve_default_fused_score(solve_p, solve_n)
-        use_sparse = _sparse_selected(opts, solve_p, problem.S, solve_n,
-                                      rules)
-        if not use_sparse:
-            check_dense_memory(solve_p, problem.S, solve_n, mode)
+                solve_p = bucket_size(problem.P)
+                solve_n = bucket_size(problem.N)
+                (prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a) = \
+                    pad_problem_arrays(prev_a, pw_a, nw_a, valid_a, stick_a,
+                                       gids_a, gv_a, solve_p, solve_n)
+            _check_tier_band_scale(prev_a, pw_a, nw_a, valid_a, stick_a,
+                                   constraints, rules)
+            mode = resolve_default_fused_score(solve_p, solve_n)
+            use_sparse = _sparse_selected(opts, solve_p, problem.S, solve_n,
+                                          rules)
+            if not use_sparse:
+                check_dense_memory(solve_p, problem.S, solve_n, mode)
+            p_real = (jax.device_put(np.float32(problem.P))
+                      if opts.shape_bucketing else None)
 
         try:
             if use_sparse:
@@ -2893,9 +2897,7 @@ def plan_pipeline(
                         opts, solve_n, constraints, rules),
                     sparse_impl=resolve_sparse_impl(None),
                     favor_min_nodes=favor_min_nodes,
-                    entry="sparse.pipeline", timer=timer,
-                    p_real=(jax.device_put(np.float32(problem.P))
-                            if opts.shape_bucketing else None))
+                    entry="sparse.pipeline", timer=timer, p_real=p_real)
             else:
                 res = _dispatch_pipeline_cold(
                     prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
@@ -2906,9 +2908,7 @@ def plan_pipeline(
                     favor_min_nodes=favor_min_nodes,
                     entry=("solve_dense.bucketed" if opts.shape_bucketing
                            else "pipeline.cold"),
-                    timer=timer,
-                    p_real=(jax.device_put(np.float32(problem.P))
-                            if opts.shape_bucketing else None))
+                    timer=timer, p_real=p_real)
         except (ValueError, TypeError):
             raise  # deterministic input errors: same on the staged path
         except Exception as e:
@@ -2932,7 +2932,7 @@ def plan_pipeline(
             (packed, counts) = res
         assign = assign[:problem.P]
         maybe_validate(problem, assign, opts.validate_assignment,
-                       "plan_pipeline")
+                       "plan_pipeline", timer=timer)
         with phase_span("plan.decode", timer=timer):
             next_map, warnings = decode_assignment(
                 problem, assign, partitions_to_assign, nodes_to_remove,
@@ -2966,30 +2966,34 @@ def _dispatch_pipeline_cold(
 
     def run(m: str):
         impl = _pipeline_cold_donating if donate else _pipeline_cold_jit
-        dev_prev = jnp.asarray(prev_a)
         t0 = rec.now()
         with phase_span("plan.pipeline.dispatch", timer=timer,
                         engine=m), \
                 _device.entry(entry):
-            out = impl(
-                dev_prev, jnp.asarray(pw_a), jnp.asarray(nw_a),
-                jnp.asarray(valid_a), jnp.asarray(stick_a),
-                jnp.asarray(gids_a), jnp.asarray(gv_a),
-                constraints, rules, max_iterations=max_iterations,
-                fused_score=m, favor_min_nodes=favor_min_nodes,
-                carry_used=carry_used, p_real=p_real)
+            # launch: the uploads and the call, up to its return (before
+            # the first blocking read).
+            with phase_span("plan.pipeline.launch", timer=timer):
+                out = impl(
+                    jnp.asarray(prev_a), jnp.asarray(pw_a),
+                    jnp.asarray(nw_a), jnp.asarray(valid_a),
+                    jnp.asarray(stick_a), jnp.asarray(gids_a),
+                    jnp.asarray(gv_a), constraints, rules,
+                    max_iterations=max_iterations, fused_score=m,
+                    favor_min_nodes=favor_min_nodes, carry_used=carry_used,
+                    p_real=p_real)
             (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
              packed, counts) = out
             # One boundary crossing for the whole pipeline: everything
             # below is host-side materialization.
             assign_np = np.asarray(assign)
         rec.observe("plan.pipeline.dispatch_s", rec.now() - t0)
-        _record_sweeps(sweeps)
-        carry = SolveCarry(prices=prices, assign=assign, used=used)
-        return (assign_np, sweeps, carry,
-                (np.asarray(d_nodes), np.asarray(d_states),
-                 np.asarray(d_ops)),
-                (np.asarray(packed), np.asarray(counts)))
+        with phase_span("plan.pipeline.fetch", timer=timer):
+            _record_sweeps(sweeps)
+            carry = SolveCarry(prices=prices, assign=assign, used=used)
+            return (assign_np, sweeps, carry,
+                    (np.asarray(d_nodes), np.asarray(d_states),
+                     np.asarray(d_ops)),
+                    (np.asarray(packed), np.asarray(counts)))
 
     try:
         return run(fused_score)
@@ -3068,22 +3072,22 @@ def _dispatch_pipeline_sparse(
     with phase_span("plan.pipeline.dispatch", timer=timer,
                     engine="sparse"), \
             _device.entry(entry):
-        (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
-         packed, counts, exh) = impl(
-            jnp.asarray(prev_a), jnp.asarray(pw_a), jnp.asarray(nw_a),
-            jnp.asarray(valid_a), jnp.asarray(stick_a),
-            jnp.asarray(gids_a), jnp.asarray(gv_a),
-            constraints, rules, max_iterations=max_iterations,
-            shortlist_k=shortlist_k, sparse_impl=sparse_impl,
-            favor_min_nodes=favor_min_nodes, carry_used=carry_used,
-            p_real=p_real)
+        with phase_span("plan.pipeline.launch", timer=timer):
+            (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
+             packed, counts, exh) = impl(
+                jnp.asarray(prev_a), jnp.asarray(pw_a), jnp.asarray(nw_a),
+                jnp.asarray(valid_a), jnp.asarray(stick_a),
+                jnp.asarray(gids_a), jnp.asarray(gv_a),
+                constraints, rules, max_iterations=max_iterations,
+                shortlist_k=shortlist_k, sparse_impl=sparse_impl,
+                favor_min_nodes=favor_min_nodes, carry_used=carry_used,
+                p_real=p_real)
         # One boundary crossing for the whole pipeline (plus the
         # exhaustion flags, which gate the host escape hatch).
         assign_np = np.asarray(assign)
         exh_np = np.asarray(exh)
     rec.observe("plan.pipeline.dispatch_s", rec.now() - t0)
     rec.set_gauge("plan.sparse.k_effective", float(shortlist_k))
-    _record_sweeps(sweeps)
     patched, replaced = _apply_sparse_fallback(
         assign_np, exh_np, prev_fb, pw_a, nw_a, valid_a, stick_a,
         gids_a, gv_a, constraints, rules)
@@ -3105,10 +3109,12 @@ def _dispatch_pipeline_sparse(
             dev_assign, jnp.asarray(pw_a), jnp.asarray(nw_a))
     else:
         carry = SolveCarry(prices=prices, assign=assign, used=used)
-    return (assign_np, sweeps, carry,
-            (np.asarray(d_nodes), np.asarray(d_states),
-             np.asarray(d_ops)),
-            (np.asarray(packed), np.asarray(counts)))
+    with phase_span("plan.pipeline.fetch", timer=timer):
+        _record_sweeps(sweeps)
+        return (assign_np, sweeps, carry,
+                (np.asarray(d_nodes), np.asarray(d_states),
+                 np.asarray(d_ops)),
+                (np.asarray(packed), np.asarray(counts)))
 
 
 def solve_converged_resilient(
@@ -3527,12 +3533,13 @@ _VALIDATE_AUTO_CELLS = 1 << 22
 
 def maybe_validate(
     problem: DenseProblem, assign: NPArray, validate: Optional[bool],
-    context: str,
+    context: str, timer=None,
 ) -> Optional[dict[str, int]]:
     """Run check_assignment per the ``validate_assignment`` policy and
     surface violations as a UserWarning (reference analogue: constraint
     problems degrade to warnings, plan.go:231-235).  Returns the counts
-    when the check ran, else None."""
+    when the check ran, else None.  The check runs in the
+    ``plan.validate`` phase span."""
     import warnings as _warnings
 
     if validate is None:
@@ -3540,7 +3547,8 @@ def maybe_validate(
             problem.P * problem.N <= _VALIDATE_AUTO_CELLS
     if not validate:
         return None
-    counts = check_assignment(problem, assign)
+    with phase_span("plan.validate", timer=timer):
+        counts = check_assignment(problem, assign)
     if any(counts.values()):
         _warnings.warn(
             f"blance_tpu {context}: solver produced a constraint-violating "

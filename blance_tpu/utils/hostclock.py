@@ -3,7 +3,8 @@
 Host-phase timing — solver host milliseconds (``plan.service``), the
 fleet planner's encode/decode phases (``fleetloop``), simulator
 ``wall_s`` (``testing.simulate`` / ``testing.fleetsim``) and
-``PhaseTimer`` totals — is *observability about this run of the
+``PhaseTimer`` totals (the spans of a ``Recorder`` on its default
+clock) — is *observability about this run of the
 program*, not replayed state: none of it may feed a canonical log or
 journal, and all of it needs a real wall clock in production.  Instead
 of sprinkling ``time.perf_counter()`` through replay-rooted modules

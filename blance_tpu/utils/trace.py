@@ -17,11 +17,11 @@ framework exposes:
 from __future__ import annotations
 
 import contextlib
+from contextlib import AbstractContextManager
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
 
-from ..obs import get_recorder
-from .hostclock import perf_now
+from ..obs import Span, get_recorder, phase_span
 
 __all__ = ["PhaseTimer", "device_profile"]
 
@@ -38,19 +38,13 @@ class PhaseTimer:
     counts: dict[str, int] = field(default_factory=dict)
     annotations: dict[str, str] = field(default_factory=dict)
 
-    @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        start = perf_now()
-        try:
-            with get_recorder().span(name):
-                yield
-        finally:
-            self._accumulate(name, perf_now() - start)
+    def phase(self, name: str) -> AbstractContextManager[Span]:
+        """Time one region as the Recorder span ``name``."""
+        return phase_span(name, timer=self, phase=name)
 
     def _accumulate(self, name: str, elapsed: float) -> None:
-        """Fold one elapsed interval into the report totals — the piece of
-        the old phase() that is NOT the span; obs.phase_span uses it to
-        time a region once while publishing both views."""
+        """Fold one elapsed interval into the report totals; obs.phase_span
+        adds each span's duration here."""
         self.totals[name] = self.totals.get(name, 0.0) + elapsed
         self.counts[name] = self.counts.get(name, 0) + 1
 

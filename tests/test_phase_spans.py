@@ -1,0 +1,185 @@
+"""plan_pipeline's host time inside named phase spans, and those spans in
+the profiler's trace.
+
+Every host step of one dense ``plan_pipeline`` call falls inside exactly
+one phase span (``plan.encode``, ``plan.pipeline.prepare``,
+``plan.pipeline.dispatch`` with ``plan.pipeline.launch`` inside it,
+``plan.pipeline.fetch``, ``plan.validate``, ``plan.decode``,
+``plan.pipeline.materialize``), all under ``plan.pipeline``; each phase
+span is also a host annotation of the same name while a profiler session
+is active, and never builds one otherwise.  The problem is shaped like a
+Couchbase bucket: one active and two replicas, server groups as the
+hierarchy, replicas outside the active's group."""
+
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from blance_tpu import HierarchyRule, Partition, PlanOptions, model
+from blance_tpu.obs import Recorder, get_recorder, phase_span, use_recorder
+from blance_tpu.obs.sinks import InMemorySink
+from blance_tpu.plan import tensor
+from blance_tpu.plan.tensor import plan_pipeline
+from blance_tpu.utils.trace import PhaseTimer
+
+P, N, GROUP = 64, 8, 2
+NODES = [f"n{i}" for i in range(N)]
+MODEL = model(primary=(0, 1), replica=(1, 2))
+
+# Span name -> PhaseTimer key, for every phase of the dense path.
+PHASES = {
+    "plan.pipeline": "pipeline",
+    "plan.encode": "encode",
+    "plan.pipeline.prepare": "prepare",
+    "plan.pipeline.dispatch": "dispatch",
+    "plan.pipeline.launch": "launch",
+    "plan.pipeline.fetch": "fetch",
+    "plan.validate": "validate",
+    "plan.decode": "decode",
+    "plan.pipeline.materialize": "materialize",
+}
+
+
+def _opts():
+    parents = {n: f"g{i // GROUP}" for i, n in enumerate(NODES)}
+    parents.update({f"g{i}": "c0" for i in range(N // GROUP)})
+    return PlanOptions(node_hierarchy=parents,
+                       hierarchy_rules={"replica": [HierarchyRule(2, 1)]})
+
+
+def _bucket(seed=7):
+    rng = np.random.default_rng(seed)
+    out = {}
+    for p in range(P):
+        ids = rng.choice(N, 3, replace=False)
+        out[str(p)] = Partition(str(p), {
+            "primary": [NODES[ids[0]]],
+            "replica": [NODES[i] for i in ids[1:]]})
+    return out
+
+
+def _plan(prev, removed, timer=None):
+    return plan_pipeline(prev, prev, NODES, [removed], [], MODEL, _opts(),
+                         timer=timer)
+
+
+@pytest.fixture(scope="module")
+def prev():
+    """A bucket map, planned once so the pipeline program is compiled
+    before any test times or traces it."""
+    start = _bucket()
+    nxt, _w, _m = _plan(start, NODES[0])
+    return nxt
+
+
+def test_host_steps_land_in_their_spans(prev, monkeypatch):
+    seen = {}
+
+    def spy(fn):
+        def wrapped(*a, **kw):
+            seen[fn.__name__] = get_recorder().current_span().name
+            return fn(*a, **kw)
+        return wrapped
+
+    for name in ("_check_tier_band_scale", "check_assignment",
+                 "_record_sweeps"):
+        monkeypatch.setattr(tensor, name, spy(getattr(tensor, name)))
+    rec = Recorder()
+    sink = InMemorySink()
+    rec.add_sink(sink)
+    timer = PhaseTimer()
+    with use_recorder(rec):
+        _plan(prev, NODES[1], timer)
+    assert seen == {"_check_tier_band_scale": "plan.pipeline.prepare",
+                    "check_assignment": "plan.validate",
+                    "_record_sweeps": "plan.pipeline.fetch"}
+    spans = {sp.name: sp for sp in sink.spans}
+    assert set(spans) == set(PHASES)
+    assert len(sink.spans) == len(PHASES)  # each phase once per call
+    top = spans["plan.pipeline"]
+    assert top.attrs == {"partitions": P, "nodes": N}
+    launch, dispatch = spans["plan.pipeline.launch"], \
+        spans["plan.pipeline.dispatch"]
+    assert launch.parent_id == dispatch.span_id
+    assert dispatch.attrs["engine"] in ("on", "off")
+    for name, sp in spans.items():
+        if name not in ("plan.pipeline", "plan.pipeline.launch"):
+            assert sp.parent_id == top.span_id, name
+    # One interval each: the timer holds the spans' own durations.
+    assert timer.totals == {PHASES[n]: sp.duration_s
+                            for n, sp in spans.items()}
+
+
+def _trace_events(log_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in PHASES:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns * 1e-9))
+    return {k: [d for _, d in sorted(v)] for k, v in out.items()}
+
+
+def test_phases_reach_the_profiler_trace(prev, tmp_path):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    timers = [PhaseTimer(), PhaseTimer()]
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        nxt, _w, _m = _plan(prev, NODES[1], timers[0])
+        _plan(nxt, NODES[2], timers[1])
+    events = _trace_events(str(tmp_path))
+    assert set(events) == set(PHASES)
+    for name, key in PHASES.items():
+        assert len(events[name]) == len(timers), name  # once per call
+        for got, timer in zip(events[name], timers):
+            want = timer.totals[key]
+            assert abs(got - want) <= 0.05 * want + 2e-4, (name, got, want)
+
+
+def test_no_profiler_no_annotation(prev, monkeypatch):
+    real = jax.profiler.TraceAnnotation
+    assert not real.is_enabled()
+
+    class Refused:
+        is_enabled = staticmethod(real.is_enabled)
+
+        def __init__(self, *a, **kw):
+            raise AssertionError("TraceAnnotation built with no profiler")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+    timer = PhaseTimer()
+    with phase_span("plan.encode", timer=timer):
+        pass
+    with timer.phase("solve"):
+        pass
+    _plan(prev, NODES[3], timer)
+    assert set(timer.totals) == set(PHASES.values()) | {"solve"}
+
+
+def test_phase_span_times_once():
+    """phase_span reads the clock only for its span, and the timer and
+    the span hold the same interval; PhaseTimer.phase is phase_span."""
+    ticks = iter([0.0, 10.0, 10.25, 20.0, 20.5])
+    rec = Recorder(clock=lambda: next(ticks))
+    sink = InMemorySink()
+    rec.add_sink(sink)
+    timer = PhaseTimer()
+    with use_recorder(rec):
+        with phase_span("plan.encode", timer=timer, phase="enc") as sp:
+            pass
+        with timer.phase("solve"):
+            pass
+    assert sp.duration_s == timer.totals["enc"] == 0.25
+    assert timer.totals["solve"] == 0.5
+    assert [s.name for s in sink.spans] == ["plan.encode", "solve"]
+    assert rec.span_totals == {"plan.encode": 0.25, "solve": 0.5}
