@@ -188,6 +188,9 @@ def default_registry() -> MetricsRegistry:
         Metric("plan.pipeline.dispatch_s", "histogram",
                "wall-clock seconds per fused pipeline device dispatch "
                "(solve + diff + pack, one program)"),
+        Metric("plan.pipeline.d2h_waits", "counter",
+               "blocking host waits on device outputs in cold and sparse "
+               "pipeline dispatches (1 per dense call)"),
         # -- sparse shortlist solver (plan/tensor.solve_sparse +
         # core/shortlist.py + parallel/sharded.solve_sparse_sharded) ----------
         Metric("plan.sparse.shortlist_build_s", "histogram",

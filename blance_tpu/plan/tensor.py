@@ -2607,6 +2607,20 @@ def solve_sparse_warm(
 # lists), which is irreducibly string-typed.
 
 
+def _host_flat(outs: tuple) -> tuple:
+    """A cold or sparse pipeline body's outputs as a dispatch returns
+    them: (assign, prices, used, host).  ``host`` holds every output the
+    host reads, all but the carry's prices and used, in the body's order
+    (assign, sweeps, d_nodes, d_states, d_ops, packed, counts[, exh]),
+    raveled into ONE flat int32 buffer, so they cross to the host in one
+    transfer (_fetch_outputs splits it).  Every one is an id, a count or
+    a flag that int32 holds.  ``assign`` also stays a device output, for
+    the carry."""
+    assign, sweeps, prices, used, *rest = outs
+    return assign, prices, used, jnp.concatenate(
+        [jnp.ravel(x).astype(jnp.int32) for x in (assign, sweeps, *rest)])
+
+
 def _pipeline_cold_impl(
     prev: jnp.ndarray,
     pweights: jnp.ndarray,
@@ -2625,15 +2639,17 @@ def _pipeline_cold_impl(
     favor_min_nodes: bool = False,
     carry_used: Optional[jnp.ndarray] = None,
     p_real: Optional[jnp.ndarray] = None,
+    host_flat: bool = False,
 ) -> tuple[jnp.ndarray, ...]:
     """Cold pipeline body: converged solve + diff(prev, out) + pack.
 
     Returns (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
-    packed, counts).  ``prices``/``used`` are the next SolveCarry's
-    tables, computed with the carry builder's exact ops so the packaged
-    carry is bitwise what carry_from_assignment would build —  without
-    a second dispatch.  The solve is the UNCHANGED converged fixpoint
-    trace, so ``assign`` is bit-identical to the staged path's.
+    packed, counts), or with ``host_flat`` (the dispatch's program) the
+    :func:`_host_flat` form.  ``prices``/``used`` are the next
+    SolveCarry's tables, computed with the carry builder's exact ops so
+    the packaged carry is bitwise what carry_from_assignment would build
+    —  without a second dispatch.  The solve is the UNCHANGED converged
+    fixpoint trace, so ``assign`` is bit-identical to the staged path's.
     """
     from ..core.encode import pack_assignment_core
     from ..moves.batch import diff_assignments
@@ -2648,8 +2664,9 @@ def _pipeline_cold_impl(
     d_nodes, d_states, d_ops = diff_assignments(
         prev, out, favor_min_nodes=favor_min_nodes)
     packed, counts = pack_assignment_core(out)
-    return (out, sweeps, prices, used, d_nodes, d_states, d_ops,
-            packed, counts)
+    outs = (out, sweeps, prices, used, d_nodes, d_states, d_ops, packed,
+            counts)
+    return _host_flat(outs) if host_flat else outs
 
 
 def _pipeline_warm_impl(
@@ -2710,13 +2727,15 @@ def _pipeline_sparse_cold_impl(
     favor_min_nodes: bool = False,
     carry_used: Optional[jnp.ndarray] = None,
     p_real: Optional[jnp.ndarray] = None,
+    host_flat: bool = False,
 ) -> tuple[jnp.ndarray, ...]:
     """Sparse pipeline body: shortlist build -> sparse converged solve
     -> diff(prev, out) -> pack, ONE traced program — the sparse variant
     of :func:`_pipeline_cold_impl` (donation preserved: prev aliases
-    into assign/packed).  Returns the cold pipeline tuple plus the
-    exhaustion flags; the dispatcher re-places flagged rows host-side
-    and re-derives diff/pack for them (rare by design)."""
+    into assign).  Returns the cold pipeline tuple plus the
+    exhaustion flags (``host_flat``: see the cold body); the dispatcher
+    re-places flagged rows host-side and re-derives diff/pack for them
+    (rare by design)."""
     from ..core.encode import pack_assignment_core
     from ..core.shortlist import build_shortlist_core
     from ..moves.batch import diff_assignments
@@ -2735,8 +2754,9 @@ def _pipeline_sparse_cold_impl(
     d_nodes, d_states, d_ops = diff_assignments(
         prev, out, favor_min_nodes=favor_min_nodes)
     packed, counts = pack_assignment_core(out)
-    return (out, sweeps, prices, used, d_nodes, d_states, d_ops,
-            packed, counts, exh)
+    outs = (out, sweeps, prices, used, d_nodes, d_states, d_ops, packed,
+            counts, exh)
+    return _host_flat(outs) if host_flat else outs
 
 
 _PIPE_COLD_STATICS = ("constraints", "rules", "axis_name",
@@ -2749,15 +2769,17 @@ _PIPE_WARM_STATICS = ("constraints", "rules", "axis_name", "node_axis",
                       "node_shards", "fused_score", "favor_min_nodes")
 
 _pipeline_cold_jit = partial(
-    jax.jit, static_argnames=_PIPE_COLD_STATICS)(_pipeline_cold_impl)
-# Donation: prev aliases into the same-shaped assign/packed outputs; the
+    jax.jit, static_argnames=_PIPE_COLD_STATICS)(
+    partial(_pipeline_cold_impl, host_flat=True))
+# Donation: prev aliases into the same-shaped assign output; the
 # warm path additionally donates the consumed carry table (single-use by
 # contract — sessions replace theirs after every attempt).  Donation is
 # supported on every backend under the pinned jax (tests assert the
 # donated buffers really are invalidated), so there is no CPU split like
 # _warm_repair_donating's.
 _pipeline_cold_donating = jax.jit(
-    _pipeline_cold_impl, static_argnames=_PIPE_COLD_STATICS,
+    partial(_pipeline_cold_impl, host_flat=True),
+    static_argnames=_PIPE_COLD_STATICS,
     donate_argnames=("prev",))
 _pipeline_warm_jit = partial(
     jax.jit, static_argnames=_PIPE_WARM_STATICS)(_pipeline_warm_impl)
@@ -2766,9 +2788,10 @@ _pipeline_warm_donating = jax.jit(
     donate_argnames=("prev", "carry_used"))
 _pipeline_sparse_jit = partial(
     jax.jit, static_argnames=_PIPE_SPARSE_STATICS)(
-    _pipeline_sparse_cold_impl)
+    partial(_pipeline_sparse_cold_impl, host_flat=True))
 _pipeline_sparse_donating = jax.jit(
-    _pipeline_sparse_cold_impl, static_argnames=_PIPE_SPARSE_STATICS,
+    partial(_pipeline_sparse_cold_impl, host_flat=True),
+    static_argnames=_PIPE_SPARSE_STATICS,
     donate_argnames=("prev",))
 
 
@@ -2949,6 +2972,26 @@ def plan_pipeline(
         return next_map, warnings, moves
 
 
+def _fetch_outputs(host, shapes: tuple) -> list:
+    """Read a dispatch's flat host buffer (:func:`_host_flat`) in ONE
+    host wait, counted as one ``plan.pipeline.d2h_waits``, and split it
+    at the static offsets of ``shapes`` (a layout that disagrees with the
+    buffer fails the reshape)."""
+    get_recorder().count("plan.pipeline.d2h_waits")
+    bounds = np.cumsum([int(np.prod(s)) for s in shapes])[:-1]
+    return [part.reshape(s) for part, s in
+            zip(np.split(np.asarray(host), bounds), shapes)]
+
+
+def _host_shapes(prev_shape: tuple) -> tuple:
+    """Shapes of the cold body's host outputs in :func:`_host_flat`'s
+    order, from prev's [P, S, R]: the diff's rows are 2*S*R ops wide
+    (moves.batch.diff_assignments)."""
+    p, s, r = prev_shape
+    diff = (p, 2 * s * r)
+    return ((p, s, r), (), diff, diff, diff, (p, s, r), (p, s))
+
+
 def _dispatch_pipeline_cold(
     prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
     constraints: Constraints, rules: Rules, *, max_iterations: int,
@@ -2959,7 +3002,7 @@ def _dispatch_pipeline_cold(
     solve_converged_resilient (retry once on the opposite engine when
     the mode came from "auto").  Returns (assign_np, sweeps,
     SolveCarry, (d_nodes, d_states, d_ops) np, (packed, counts) np) —
-    everything off-device exactly once, at the end."""
+    everything off-device in one host wait, at the end."""
     import warnings as _warnings
 
     rec = get_recorder()
@@ -2981,19 +3024,17 @@ def _dispatch_pipeline_cold(
                     max_iterations=max_iterations, fused_score=m,
                     favor_min_nodes=favor_min_nodes, carry_used=carry_used,
                     p_real=p_real)
-            (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
-             packed, counts) = out
+            assign, prices, used, host = out
             # One boundary crossing for the whole pipeline: everything
             # below is host-side materialization.
-            assign_np = np.asarray(assign)
+            (assign_np, sweeps_np, *darrs, packed_np,
+             counts_np) = _fetch_outputs(host, _host_shapes(prev_a.shape))
         rec.observe("plan.pipeline.dispatch_s", rec.now() - t0)
         with phase_span("plan.pipeline.fetch", timer=timer):
-            _record_sweeps(sweeps)
+            _record_sweeps(sweeps_np)
             carry = SolveCarry(prices=prices, assign=assign, used=used)
-            return (assign_np, sweeps, carry,
-                    (np.asarray(d_nodes), np.asarray(d_states),
-                     np.asarray(d_ops)),
-                    (np.asarray(packed), np.asarray(counts)))
+            return (assign_np, sweeps_np, carry, tuple(darrs),
+                    (packed_np, counts_np))
 
     try:
         return run(fused_score)
@@ -3073,8 +3114,7 @@ def _dispatch_pipeline_sparse(
                     engine="sparse"), \
             _device.entry(entry):
         with phase_span("plan.pipeline.launch", timer=timer):
-            (assign, sweeps, prices, used, d_nodes, d_states, d_ops,
-             packed, counts, exh) = impl(
+            assign, prices, used, host = impl(
                 jnp.asarray(prev_a), jnp.asarray(pw_a), jnp.asarray(nw_a),
                 jnp.asarray(valid_a), jnp.asarray(stick_a),
                 jnp.asarray(gids_a), jnp.asarray(gv_a),
@@ -3082,10 +3122,11 @@ def _dispatch_pipeline_sparse(
                 shortlist_k=shortlist_k, sparse_impl=sparse_impl,
                 favor_min_nodes=favor_min_nodes, carry_used=carry_used,
                 p_real=p_real)
-        # One boundary crossing for the whole pipeline (plus the
-        # exhaustion flags, which gate the host escape hatch).
-        assign_np = np.asarray(assign)
-        exh_np = np.asarray(exh)
+        # One boundary crossing for the whole pipeline, the exhaustion
+        # flags (which gate the host escape hatch) included.
+        (assign_np, sweeps_np, *darrs, packed_np, counts_np,
+         exh_np) = _fetch_outputs(
+            host, _host_shapes(prev_a.shape) + (prev_a.shape[:1],))
     rec.observe("plan.pipeline.dispatch_s", rec.now() - t0)
     rec.set_gauge("plan.sparse.k_effective", float(shortlist_k))
     patched, replaced = _apply_sparse_fallback(
@@ -3094,8 +3135,8 @@ def _dispatch_pipeline_sparse(
     if replaced:
         # The fused diff/pack ran before the host fallback patched the
         # flagged rows: re-derive both against the final assignment and
-        # rebuild the carry from it (one small extra dispatch on the
-        # escape-hatch path only).
+        # rebuild the carry from it (one small extra dispatch, and one
+        # more host wait, on the escape-hatch path only).
         from ..core.encode import pack_assignment
         from ..moves.batch import diff_assignments
 
@@ -3107,14 +3148,15 @@ def _dispatch_pipeline_sparse(
         packed, counts = pack_assignment(dev_assign)
         carry = carry_from_assignment(
             dev_assign, jnp.asarray(pw_a), jnp.asarray(nw_a))
+        rec.count("plan.pipeline.d2h_waits")
+        (*darrs, packed_np, counts_np) = jax.device_get(
+            (d_nodes, d_states, d_ops, packed, counts))
     else:
         carry = SolveCarry(prices=prices, assign=assign, used=used)
     with phase_span("plan.pipeline.fetch", timer=timer):
-        _record_sweeps(sweeps)
-        return (assign_np, sweeps, carry,
-                (np.asarray(d_nodes), np.asarray(d_states),
-                 np.asarray(d_ops)),
-                (np.asarray(packed), np.asarray(counts)))
+        _record_sweeps(sweeps_np)
+        return (assign_np, sweeps_np, carry, tuple(darrs),
+                (packed_np, counts_np))
 
 
 def solve_converged_resilient(

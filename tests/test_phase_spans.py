@@ -7,7 +7,9 @@ one phase span (``plan.encode``, ``plan.pipeline.prepare``,
 ``plan.pipeline.fetch``, ``plan.validate``, ``plan.decode``,
 ``plan.pipeline.materialize``), all under ``plan.pipeline``; each phase
 span is also a host annotation of the same name while a profiler session
-is active, and never builds one otherwise.  The problem is shaped like a
+is active, and never builds one otherwise.  The call reads the device's
+outputs to the host in one wait (``plan.pipeline.d2h_waits``), before
+``plan.pipeline.fetch`` opens.  The problem is shaped like a
 Couchbase bucket: one active and two replicas, server groups as the
 hierarchy, replicas outside the active's group."""
 
@@ -43,11 +45,12 @@ PHASES = {
 }
 
 
-def _opts():
+def _opts(**kw):
     parents = {n: f"g{i // GROUP}" for i, n in enumerate(NODES)}
     parents.update({f"g{i}": "c0" for i in range(N // GROUP)})
     return PlanOptions(node_hierarchy=parents,
-                       hierarchy_rules={"replica": [HierarchyRule(2, 1)]})
+                       hierarchy_rules={"replica": [HierarchyRule(2, 1)]},
+                       **kw)
 
 
 def _bucket(seed=7):
@@ -111,6 +114,43 @@ def test_host_steps_land_in_their_spans(prev, monkeypatch):
     # One interval each: the timer holds the spans' own durations.
     assert timer.totals == {PHASES[n]: sp.duration_s
                             for n, sp in spans.items()}
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_outputs_reach_the_host_in_one_wait(prev, monkeypatch, sparse):
+    """One plan_pipeline call waits on the device once: every output the
+    host needs is numpy before ``plan.pipeline.fetch`` opens."""
+    real, at_fetch = tensor._record_sweeps, []
+
+    def spy(sweeps):
+        rec = get_recorder()
+        at_fetch.append((rec.current_span().name, type(sweeps),
+                         rec.counters.get("plan.pipeline.d2h_waits")))
+        return real(sweeps)
+
+    monkeypatch.setattr(tensor, "_record_sweeps", spy)
+    name = "_dispatch_pipeline_sparse" if sparse else \
+        "_dispatch_pipeline_cold"
+    dispatch, outs = getattr(tensor, name), []
+
+    def keep(*a, **kw):
+        outs.append(dispatch(*a, **kw))
+        return outs[-1]
+
+    monkeypatch.setattr(tensor, name, keep)
+    rec = Recorder()
+    with use_recorder(rec):
+        plan_pipeline(prev, prev, NODES, [NODES[1]], [], MODEL,
+                      _opts(sparse=sparse))
+    assert rec.counters["plan.pipeline.d2h_waits"] == 1
+    assert rec.counters.get("plan.sparse.dense_fallback_rows", 0) == 0
+    assert at_fetch == [("plan.pipeline.fetch", np.ndarray, 1)]
+    (assign, sweeps, carry, darrs, packs), = outs
+    for a in (assign, sweeps, *darrs, *packs):
+        assert type(a) is np.ndarray
+    # The carry keeps its device arrays for the next warm start.
+    for a in carry:
+        assert isinstance(a, jax.Array)
 
 
 def _trace_events(log_dir):

@@ -14,6 +14,8 @@ Contracts pinned here:
 - the warm one-sweep repair runs bit-identically through the fused
   Pallas score kernel (interpret mode) — delta replans cover the fused
   scoring path;
+- the cold and sparse dispatch programs carry every host-bound output in
+  one flat int32 buffer that splits back bit for bit, read in one wait;
 - donated input buffers are actually invalidated after dispatch;
 - mesh_shape_for/make_mesh_auto factorization invariants and the
   declarative shard-layout tables the runtime and the shape audit share.
@@ -40,7 +42,17 @@ from blance_tpu.moves.batch import calc_all_moves
 from blance_tpu.obs import Recorder, use_recorder
 from blance_tpu.plan.session import PlannerSession
 from blance_tpu.plan.tensor import (
+    _PIPE_COLD_STATICS,
+    _PIPE_SPARSE_STATICS,
+    _dispatch_pipeline_cold,
+    _dispatch_pipeline_sparse,
+    _fetch_outputs,
+    _host_shapes,
     _pipeline_cold_donating,
+    _pipeline_cold_impl,
+    _pipeline_cold_jit,
+    _pipeline_sparse_cold_impl,
+    _pipeline_sparse_jit,
     _pipeline_warm_donating,
     _pipeline_warm_jit,
     carry_from_assignment,
@@ -327,6 +339,75 @@ def test_warm_pipeline_fused_interpret_matches_matrix():
     assert bool(r_matrix[3]) and bool(r_fused[3])  # both accepted
     for a, b in zip(r_matrix, r_fused):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_host_buffer_splits_back_to_every_output(sparse):
+    """The dispatch program's one flat host buffer, split on the host,
+    gives back bit for bit every output of the pipeline body the host
+    reads, each read on its own; and the dispatch hands the host those
+    arrays, with the carry's tables left on the device."""
+    args = _dense(48, 8, seed=29)
+    if sparse:
+        kw = dict(max_iterations=10, shortlist_k=4, sparse_impl="xla",
+                  favor_min_nodes=False)
+        body = jax.jit(_pipeline_sparse_cold_impl,
+                       static_argnames=_PIPE_SPARSE_STATICS)
+        flat, extra = _pipeline_sparse_jit, ((48,),)
+        got = _dispatch_pipeline_sparse(*args, entry="sparse.pipeline",
+                                        **kw)
+    else:
+        kw = dict(max_iterations=10, fused_score="off",
+                  favor_min_nodes=False)
+        body = jax.jit(_pipeline_cold_impl,
+                       static_argnames=_PIPE_COLD_STATICS)
+        flat, extra = _pipeline_cold_jit, ()
+        got = _dispatch_pipeline_cold(*args, allow_fallback=False,
+                                      entry="pipeline.cold", **kw)
+    want = [np.asarray(x) for x in body(
+        *(jnp.asarray(a) for a in args[:7]), args[7], args[8], **kw)]
+    w_assign, w_sweeps, w_prices, w_used, *w_host = want
+    assign, prices, used, host = flat(
+        *(jnp.asarray(a) for a in args[:7]), args[7], args[8], **kw)
+    split = _fetch_outputs(host, _host_shapes(args[0].shape) + extra)
+    for g, w in zip(split, [w_assign, w_sweeps, *w_host], strict=True):
+        assert g.dtype == np.int32 and g.shape == w.shape
+        assert np.array_equal(g, w.astype(np.int32))
+    assert np.array_equal(np.asarray(assign), w_assign)
+    assert np.array_equal(np.asarray(prices), w_prices)
+    assert np.array_equal(np.asarray(used), w_used)
+
+    assign, sweeps, carry, darrs, packs = got
+    for g, w in zip((assign, sweeps, *darrs, *packs),
+                    [w_assign, w_sweeps, *w_host[:5]], strict=True):
+        assert np.array_equal(g, w)
+    assert np.array_equal(np.asarray(carry.prices), w_prices)
+    assert np.array_equal(np.asarray(carry.used), w_used)
+
+
+def test_sparse_dispatch_rediffs_patched_rows_in_a_second_wait():
+    """K=1 exhausts rows; the host fallback patches them, and the diff
+    and pack handed back are those of the patched assignment, read in
+    one more host wait."""
+    from blance_tpu.moves.batch import diff_assignments
+
+    args = _dense(96, 16, seed=6)
+    rec = Recorder()
+    with use_recorder(rec):
+        assign, _sweeps, carry, darrs, (packed, counts) = \
+            _dispatch_pipeline_sparse(
+                *args, max_iterations=10, shortlist_k=1, sparse_impl="xla",
+                favor_min_nodes=False, entry="sparse.pipeline")
+    assert rec.counters["plan.sparse.dense_fallback_rows"] > 0
+    assert rec.counters["plan.pipeline.d2h_waits"] == 2
+    dev = jnp.asarray(assign)
+    want = diff_assignments(jnp.asarray(args[0]), dev)
+    for g, w in zip(darrs, want, strict=True):
+        assert np.array_equal(g, np.asarray(w))
+    w_packed, w_counts = pack_assignment(dev)
+    assert np.array_equal(packed, np.asarray(w_packed))
+    assert np.array_equal(counts, np.asarray(w_counts))
+    assert np.array_equal(np.asarray(carry.assign), assign)
 
 
 # ---------------------------------------------------------------------------
