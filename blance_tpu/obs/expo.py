@@ -405,6 +405,10 @@ def default_registry() -> MetricsRegistry:
         Metric("fleet.d2h_waits", "counter",
                "blocking host reads of fleet batch outputs (one per "
                "output of each class dispatch: 3 per class batch)"),
+        Metric("fleet.validate_refused", "counter",
+               "fleet tenants refused by the tier-band scale guard (one "
+               "vectorized pass per batch class, plan/fleet.py "
+               "tier_band_refusals)"),
         Metric("fleet.tenants", "gauge",
                "tenant control loops registered with the fleet rollup"),
         Metric("fleet.converge_cycles", "gauge",

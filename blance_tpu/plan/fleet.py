@@ -69,8 +69,9 @@ from .tensor import (
     Constraints,
     Rules,
     SolveCarry,
-    _check_tier_band_scale,
     _solve_dense_converged_impl,
+    _tier_band_bounds,
+    _tier_band_error,
     _used_by_state,
     _warm_repair,
     resolve_default_fused_score,
@@ -78,7 +79,8 @@ from .tensor import (
 )
 
 __all__ = ["TenantProblem", "BatchClass", "FleetResult", "batch_class_of",
-           "validate_tenant", "solve_fleet", "FLEET_AXIS"]
+           "validate_tenant", "tier_band_refusals", "refused_tenants",
+           "solve_fleet", "FLEET_AXIS"]
 
 # Default mesh axis name for fleet batch sharding (make_mesh's "parts"
 # axis is accepted too — any 1-D mesh works, the axis carries no
@@ -172,14 +174,13 @@ def batch_class_of(t: TenantProblem) -> BatchClass:
 
 
 def validate_tenant(t: TenantProblem) -> None:
-    """Raise ValueError when one tenant's problem cannot be solved —
-    the per-tenant preconditions the single-problem entry points check,
-    plus cross-array shape consistency (a malformed array would
-    otherwise only explode inside the batched solve).  solve_fleet runs
-    this for every admitted tenant (a raise fails the whole call); the
-    plan service runs it per request BEFORE batching, so one tenant's
-    bad arrays fail that request alone instead of its co-batched
-    neighbors."""
+    """Raise ValueError when one tenant's arrays are malformed: the
+    structural checks — cross-array shape consistency (a malformed
+    array would otherwise only explode inside the batched solve) and
+    slot depth against the constraints — each a keyed diagnostic.  The
+    tier-band scale guard needs well-shaped arrays and runs after these,
+    once per batch class over many tenants (:func:`tier_band_refusals`).
+    """
     prev = np.asarray(t.prev)
     if prev.ndim != 3:
         raise ValueError(
@@ -207,10 +208,72 @@ def validate_tenant(t: TenantProblem) -> None:
         raise ValueError(
             f"tenant {t.key!r}: prev slot depth R={r} "
             f"< max constraints {max(t.constraints)}")
-    # Host-side guard parity with the single-problem entry points.
-    _check_tier_band_scale(
-        t.prev, t.partition_weights, t.node_weights, t.valid_node,
-        t.stickiness, t.constraints, t.rules)
+
+
+def tier_band_refusals(tenants: Sequence[TenantProblem], *,
+                       recorder=None,
+                       record: bool = True) -> dict[int, ValueError]:
+    """The single-problem entry points' tier-band scale guard
+    (plan/tensor.py ``_check_tier_band_scale``) over structurally valid,
+    dtype-normalized tenants: one vectorized pass per batch class
+    (``_tier_band_bounds``), every tenant's bound checked against the
+    same limit.  Returns ``{index: ValueError}`` for the tenants whose
+    bound overflows, each with the single-problem guard's message.
+    Rule-less and empty tenants are exempt, as there.
+
+    obs: one ``fleet.validate`` span (``tenants``, ``classes``,
+    ``refused`` attrs; also a host annotation in a profiler trace) and
+    the ``fleet.validate_refused`` counter."""
+    rec = recorder if recorder is not None else get_recorder()
+    refused: dict[int, ValueError] = {}
+    with phase_span("fleet.validate", recorder=rec,
+                    tenants=len(tenants)) as span:
+        by_class: dict[BatchClass, list[int]] = {}
+        for i, t in enumerate(tenants):
+            if any(rl for rl in t.rules) and t.prev.size and \
+                    t.node_weights.shape[0]:
+                by_class.setdefault(batch_class_of(t), []).append(i)
+        for k, idxs in by_class.items():
+            group = [tenants[i] for i in idxs]
+            bounds = _tier_band_bounds(
+                [t.prev for t in group],
+                [t.partition_weights for t in group],
+                [t.node_weights for t in group],
+                [t.valid_node for t in group],
+                [t.stickiness for t in group], k.constraints)
+            for i, t, bound in zip(idxs, group, bounds.tolist()):
+                err = _tier_band_error(bound, t.prev.shape[0],
+                                       t.node_weights, t.valid_node,
+                                       k.constraints)
+                if err is not None:
+                    refused[i] = err
+        span.attrs.update(classes=len(by_class), refused=len(refused))
+    if record and refused:
+        rec.count("fleet.validate_refused", len(refused))
+    return refused
+
+
+def refused_tenants(tenants: Sequence[TenantProblem], *, recorder=None,
+                    record: bool = True) -> dict[int, ValueError]:
+    """Why each refused tenant cannot be solved, ``{index: error}``:
+    :func:`validate_tenant`'s structural checks per tenant, then
+    :func:`tier_band_refusals` once per batch class over the tenants
+    that passed them.  ``tenants`` are dtype-normalized
+    (``_normalized``).  solve_fleet raises the first; the plan service
+    fails each refused request alone."""
+    refused: dict[int, ValueError] = {}
+    shaped: list[int] = []
+    for i, t in enumerate(tenants):
+        try:
+            validate_tenant(t)
+        except ValueError as e:
+            refused[i] = e
+        else:
+            shaped.append(i)
+    bands = tier_band_refusals([tenants[i] for i in shaped],
+                               recorder=recorder, record=record)
+    refused.update({shaped[j]: e for j, e in bands.items()})
+    return refused
 
 
 # -- batched device programs -------------------------------------------------
@@ -541,12 +604,19 @@ def solve_fleet(
     per-element flags the single warm path checks) fall back into the
     class's cold batch, exactly like a session's warm decline.
 
+    Every tenant is checked before any dispatch
+    (:func:`refused_tenants`): the structural checks per tenant, then
+    the tier-band scale guard once per batch class, vectorized.  The
+    first refused tenant in input order raises its ValueError and
+    nothing is solved.
+
     ``mesh`` (1-D) shards each class's batch axis over the devices via
     shard_map — tenant solves are independent, so this is pure
     data-parallel scale-out.  ``fused_score`` None resolves the module
     default per class shape, like every other solve entry point.
 
-    obs: per-batch ``fleet.batch_tenants`` / ``fleet.batch_occupancy``
+    obs: the ``fleet.validate`` span of the tier-band pass; per-batch
+    ``fleet.batch_tenants`` / ``fleet.batch_occupancy``
     histograms and a ``fleet.dispatch`` span per device dispatch
     (``warm``, ``engine`` = the class's resolved ``fused_score``,
     ``tenants``, ``klass`` attrs) with ``fleet.launch`` (uploads and
@@ -562,15 +632,37 @@ def solve_fleet(
     ``fleet.dispatch`` span's attrs so a request's device dispatch is
     findable from its trace id in Perfetto and the JSONL sink.
     """
+    tenants = [_normalized(t) for t in problems]
+    # Validate FIRST: a malformed prev must surface as the keyed
+    # per-tenant diagnostic, not an opaque shape-unpack error.
+    refused = refused_tenants(tenants, recorder=recorder, record=record)
+    if refused:
+        raise refused[min(refused)]
+    return _solve_checked(
+        tenants, mesh=mesh, max_iterations=max_iterations,
+        fused_score=fused_score, record=record, recorder=recorder,
+        trace_ids=trace_ids, batch_floor=batch_floor)
+
+
+def _solve_checked(
+    tenants: Sequence[TenantProblem],
+    *,
+    mesh=None,
+    max_iterations: int = 10,
+    fused_score: Optional[str] = None,
+    record: bool = True,
+    recorder=None,
+    trace_ids: Optional[dict[str, str]] = None,
+    batch_floor: int = 1,
+) -> list[FleetResult]:
+    """:func:`solve_fleet` past its checks: dtype-normalized tenants
+    that :func:`refused_tenants` admitted.  The plan service calls this
+    directly once it has checked its batch and failed the refused
+    requests alone."""
     rec = recorder if recorder is not None else get_recorder()
     results: dict[int, FleetResult] = {}
-    tenants = [_normalized(t) for t in problems]
-
     by_class: dict[BatchClass, list[int]] = {}
     for i, t in enumerate(tenants):
-        # Validate FIRST: a malformed prev must surface as the keyed
-        # per-tenant diagnostic, not an opaque shape-unpack error.
-        validate_tenant(t)
         p, s, _r = t.prev.shape
         n = t.node_weights.shape[0]
         if p == 0 or n == 0 or s == 0:

@@ -60,7 +60,13 @@ from ..obs import get_recorder
 from ..utils.hostclock import perf_now
 from ..obs.tracectx import RequestTimeline, TraceContext, TraceIdSource
 from .carry import CarryCache
-from .fleet import FleetResult, TenantProblem, solve_fleet, validate_tenant
+from .fleet import (
+    FleetResult,
+    TenantProblem,
+    _normalized,
+    _solve_checked,
+    refused_tenants,
+)
 
 if TYPE_CHECKING:  # annotation-only
     from jax.sharding import Mesh
@@ -86,6 +92,12 @@ class _Request:
 
 
 _STOP = object()  # queue sentinel: drain and exit
+
+
+def _fail(r: _Request, e: BaseException) -> None:
+    """Fail one request alone; its co-batched neighbors still solve."""
+    if not r.future.done():
+        r.future.set_exception(e)
 
 
 class PlanService:
@@ -381,18 +393,17 @@ class PlanService:
         return batch, False
 
     def _with_cached_carry(self, t: TenantProblem) -> TenantProblem:
-        """Validate the request and attach the tenant's cached warm
-        carry when it is warm-eligible: an explicit carry passes
-        through untouched; otherwise a cached carry is consumed and
-        used iff it matches the request's ``prev`` by value AND the
-        request states its delta (``dirty``).  Cold requests count a
-        carry miss, mirroring PlannerSession.replan's accounting.
+        """Attach the tenant's cached warm carry to a checked request
+        when it is warm-eligible: an explicit carry passes through
+        untouched; otherwise a cached carry is consumed and used iff it
+        matches the request's ``prev`` by value AND the request states
+        its delta (``dirty``).  Cold requests count a carry miss,
+        mirroring PlannerSession.replan's accounting.
 
-        Validation runs HERE (per request, inside the dispatcher's
-        fail-alone guard) rather than only inside solve_fleet, so one
-        tenant's bad arrays fail that request alone — never its
-        co-batched neighbors."""
-        validate_tenant(t)
+        The batch's checks run before this, in ``_checked`` (inside the
+        dispatcher's fail-alone guard), so a refused request fails
+        alone — never its co-batched neighbors — and leaves its cached
+        carry unconsumed."""
         if t.carry is not None:
             return t
         carry, cached_dirty = self.carry_cache.consume(
@@ -402,6 +413,28 @@ class PlanService:
             return t
         return dataclasses.replace(
             t, carry=carry, dirty=t.dirty | cached_dirty)
+
+    def _checked(self, batch: list[_Request]) -> list[
+            tuple[_Request, TenantProblem]]:
+        """The batch's requests that may solve, each with its
+        dtype-normalized problem; every other request's future fails
+        alone with its own error.  The structural checks run per
+        request and the tier-band scale guard once per batch class over
+        the requests that passed them (plan/fleet.py
+        ``refused_tenants``), so each tenant's bound is computed once
+        per batch: solve_fleet's own checks are not repeated."""
+        shaped: list[tuple[_Request, TenantProblem]] = []
+        for r in batch:
+            try:
+                shaped.append((r, _normalized(r.problem)))
+            except Exception as e:
+                _fail(r, e)
+        refused = refused_tenants([t for _, t in shaped],
+                                  recorder=self._rec)
+        for j, (r, _t) in enumerate(shaped):
+            if j in refused:
+                _fail(r, refused[j])
+        return [rt for j, rt in enumerate(shaped) if j not in refused]
 
     def _solve_batch(self, problems: list[TenantProblem],
                      trace_ids: dict[str, str]) -> tuple[
@@ -413,7 +446,7 @@ class PlanService:
         rec = self._rec
         t_start = rec.now()
         w0 = perf_now()
-        results = solve_fleet(
+        results = _solve_checked(
             problems, mesh=self.mesh,
             max_iterations=self.max_iterations, recorder=rec,
             trace_ids=trace_ids, batch_floor=self.batch_floor)
@@ -457,18 +490,17 @@ class PlanService:
                 rec.set_gauge("fleet.queue_depth",
                               float(self._queue.qsize()))
                 t_batched = rec.now()
-                pairs = []
                 for r in batch:
                     if r.timeline is not None:
                         r.timeline.mark("coalesce", t_batched)
+                pairs = []
+                for r, t in self._checked(batch):
                     try:
-                        pairs.append(
-                            (r, self._with_cached_carry(r.problem)))
+                        pairs.append((r, self._with_cached_carry(t)))
                     except Exception as e:
-                        # A malformed request fails alone; its
+                        # A failing request fails alone; its
                         # co-batched neighbors still solve.
-                        if not r.future.done():
-                            r.future.set_exception(e)
+                        _fail(r, e)
                 if pairs:
                     trace_ids = {
                         r.problem.key: r.timeline.ctx.trace_id

@@ -36,6 +36,7 @@ stay globally consistent while scores stay local.
 from __future__ import annotations
 
 import contextlib
+import itertools
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -1795,16 +1796,89 @@ def _record_sweeps(sweeps: object) -> None:
     rec.set_attr("sweeps", n)
 
 
+def _tier_band_bounds(prevs, pweights, nweights, valid, stickiness,
+                      constraints) -> np.ndarray:
+    """bound[B]: the within-tier score mass estimate of
+    _check_tier_band_scale for each of B problems of one batch class.
+
+    Each argument is a length-B sequence of one problem's host arrays,
+    every problem with P > 0, N > 0, S > 0 and the same S, R and
+    ``constraints``; P and N may differ.  The problems lie end to end,
+    not padded, so no pad row or node enters any term: node ids are
+    offset by each problem's first node, one bincount fills every
+    problem's nodes, and the maxima reduce over each problem's own
+    segment.  The two sums run on each problem's own contiguous slice,
+    so every bound equals, bit for bit in float64, the arithmetic on
+    that problem's arrays alone.  The bound is the larger of the
+    capacity-rail fill and the prev map's seeded fill per node weight,
+    plus max stickiness, plus the largest negative-weight boost."""
+    p = [np.shape(x)[0] for x in prevs]
+    n = [np.shape(w)[0] for w in nweights]
+    p_at = [0, *itertools.accumulate(p)]  # each problem's first row, ...
+    n_at = [0, *itertools.accumulate(n)]  # ... first node; then the totals
+    pw = np.concatenate(pweights, dtype=np.float64)
+    nw = np.concatenate(nweights, dtype=np.float64)
+    ids = np.concatenate([np.ravel(x) for x in prevs], dtype=np.int64)
+    width = ids.size // p_at[-1]  # S * R slots per partition
+    per_problem = [k * width for k in p]
+    # Skewed seed: each prev map's weighted fill per node.  Ids outside
+    # [0, N) (negative ones wrap high as unsigned) count toward no node:
+    # they land in the trash bin past the last one.
+    bins = ids + np.repeat(n_at[:-1], per_problem)
+    bins[ids.view(np.uint64) >= np.repeat(n, per_problem)] = n_at[-1]
+    fill = np.bincount(bins, weights=np.repeat(pw, width),
+                       minlength=n_at[-1] + 1)[:-1]
+    w_div = np.where(nw > 0, nw, 1.0)
+    seed_term = np.maximum.reduceat(fill / w_div, n_at[:-1])
+    # Balanced ceiling: every constrained slot's capacity rail lands
+    # ~K * total_w * share on a node; dividing by the node's weight
+    # cancels the share for uniform shares.
+    cap_w = np.where(np.concatenate(valid, dtype=bool) & (nw >= 0),
+                     np.maximum(nw, 1.0), 0.0)
+    total_w = np.array([pw[a:b].sum() for a, b in zip(p_at, p_at[1:])])
+    cap_total = np.array([cap_w[a:b].sum() for a, b in zip(n_at, n_at[1:])])
+    k_total = float(sum(max(int(c), 0) for c in constraints))
+    rail_term = k_total * total_w / np.maximum(cap_total, 1.0)
+    bound = np.where(seed_term > rail_term, seed_term, rail_term)
+    # Stickiness is [P, S] per problem: its rows start where prev's do.
+    stick = np.concatenate([np.ravel(s) for s in stickiness],
+                           dtype=np.float64)
+    s_at = [a * (stick.size // p_at[-1]) for a in p_at[:-1]]
+    bound = bound + np.maximum.reduceat(stick, s_at)
+    return bound + np.maximum.reduceat(np.maximum(-nw, 0.0), n_at[:-1])
+
+
+def _tier_band_error(bound: float, p: int, nweights, valid,
+                     constraints) -> Optional[ValueError]:
+    """The tier-band overflow error for one problem whose bound (from
+    _tier_band_bounds) eats into the band, else None."""
+    if bound < _TIER_BAND_HEADROOM * _RULE_TIER:
+        return None
+    nw = np.asarray(nweights, np.float64)
+    usable = int(np.count_nonzero(np.asarray(valid, bool) & (nw >= 0)))
+    k_total = float(sum(max(int(c), 0) for c in constraints))
+    return ValueError(
+        f"hierarchy tier band overflow: within-tier score mass "
+        f"~{bound:.0f} >= {_TIER_BAND_HEADROOM:.2f} * _RULE_TIER "
+        f"({_RULE_TIER:.0f}) — at this partitions-per-node scale "
+        f"(P={p}, usable N={usable}, "
+        f"slots={k_total:.0f}) the band test that separates hierarchy "
+        f"tiers would misclassify rule conformance.  Add nodes, split "
+        f"the problem, or raise _RULE_TIER in concert with "
+        f"_RULE_MISS/_INF (blance_tpu/plan/tensor.py)")
+
+
 def _check_tier_band_scale(prev, pweights, nweights, valid, stickiness,
                            constraints, rules) -> None:
     """Assert the tier-equality band's scale assumption (see _RULE_TIER).
 
     Estimates the largest within-tier score mass a node can carry —
     the per-node fill term at its capacity rail AND at the prev map's
-    seeded skew, plus max stickiness and max negative-weight boost —
-    and raises ValueError when it eats into the _RULE_TIER/2 band
-    (headroom _TIER_BAND_HEADROOM).  Rule-less problems never consult
-    the band and are exempt.  Host-side only: silently skipped under a
+    seeded skew, plus max stickiness and max negative-weight boost
+    (_tier_band_bounds, here on a batch of one) — and raises ValueError
+    when it eats into the _RULE_TIER/2 band (headroom
+    _TIER_BAND_HEADROOM).  Rule-less problems never consult the band
+    and are exempt.  Host-side only: silently skipped under a
     jit/shard_map trace (the host entry already checked concrete
     values).  Cost: one vectorized bincount over prev (a few ms at
     the 100k-partition north star), memoized per (prev identity,
@@ -1837,36 +1911,11 @@ def _check_tier_band_scale(prev, pweights, nweights, valid, stickiness,
                    float(nw.min()), float(nw.max()), int(valid.sum()))
     if _tier_scale_memo.get(key) == fingerprint:
         return
-    total_w = float(pw.sum())
-    cap_w = np.where(valid & (nw >= 0), np.maximum(nw, 1.0), 0.0)
-    w_div = np.where(nw > 0, nw, 1.0)
-    # Balanced ceiling: every constrained slot's capacity rail lands
-    # ~K * total_w * share on a node; dividing by the node's weight
-    # cancels the share for uniform shares.
-    k_total = float(sum(max(int(c), 0) for c in constraints))
-    rail_term = k_total * total_w / max(float(cap_w.sum()), 1.0)
-    # Skewed seed: the prev map's actual per-node weighted fill
-    # (bincount, not add.at — vectorized, so the guard stays a few ms
-    # even at 100k partitions and never taxes the warm replan path).
-    ids = prev.reshape(prev.shape[0], -1)
-    w_rep = np.broadcast_to(pw[:, None], ids.shape)
-    m = ids >= 0
-    fill = np.bincount(ids[m].ravel(), weights=w_rep[m].ravel(),
-                       minlength=n)[:n]
-    seed_term = float((fill / w_div).max()) if n else 0.0
-    bound = max(rail_term, seed_term)
-    bound += float(stick.max()) if stick.size else 0.0
-    bound += float(np.maximum(-nw, 0.0).max())
-    if bound >= _TIER_BAND_HEADROOM * _RULE_TIER:
-        raise ValueError(
-            f"hierarchy tier band overflow: within-tier score mass "
-            f"~{bound:.0f} >= {_TIER_BAND_HEADROOM:.2f} * _RULE_TIER "
-            f"({_RULE_TIER:.0f}) — at this partitions-per-node scale "
-            f"(P={prev.shape[0]}, usable N={int(cap_w.nonzero()[0].size)}, "
-            f"slots={k_total:.0f}) the band test that separates hierarchy "
-            f"tiers would misclassify rule conformance.  Add nodes, split "
-            f"the problem, or raise _RULE_TIER in concert with "
-            f"_RULE_MISS/_INF (blance_tpu/plan/tensor.py)")
+    bound = float(_tier_band_bounds([prev], [pw], [nw], [valid], [stick],
+                                    constraints)[0])
+    err = _tier_band_error(bound, prev.shape[0], nw, valid, constraints)
+    if err is not None:
+        raise err
     if len(_tier_scale_memo) >= 256:  # bound a long-lived process's memo
         _tier_scale_memo.clear()
     _tier_scale_memo[key] = fingerprint

@@ -31,17 +31,21 @@ import jax
 import jax.numpy as jnp
 
 from blance_tpu.core.encode import bucket_size, pad_problem_arrays, pad_to
-from blance_tpu.obs import Recorder, use_recorder
+from blance_tpu.obs import InMemorySink, Recorder, use_recorder
 from blance_tpu.plan.carry import (
     CarryCache,
     capacity_shrank,
     effective_dirty,
     pad_carry_nodes,
 )
+from blance_tpu.plan import fleet as fleet_mod
+from blance_tpu.plan import tensor as tensor_mod
 from blance_tpu.plan.fleet import (
     TenantProblem,
+    _normalized,
     batch_class_of,
     solve_fleet,
+    tier_band_refusals,
 )
 from blance_tpu.plan.service import PlanService, PlanServiceClosed
 from blance_tpu.plan.session import PlannerSession
@@ -713,6 +717,215 @@ def test_service_invalid_tenant_fails_alone_not_the_batch():
     assert isinstance(bad_res, ValueError)
     assert "slot depth" in str(bad_res)
     assert np.array_equal(good_res.assign, solve_sequential(good)[0])
+
+
+# -- the tier-band scale guard, batched per class -----------------------------
+
+
+def scalar_tier_band_bound(t):
+    """The tier-band bound as the single-problem guard computes it on
+    one tenant's own unpadded arrays, in float64 — the plain reference
+    the batched bound must equal bit for bit."""
+    prev = np.asarray(t.prev)
+    pw = np.asarray(t.partition_weights, np.float64)
+    nw = np.asarray(t.node_weights, np.float64)
+    valid = np.asarray(t.valid_node, bool)
+    stick = np.asarray(t.stickiness, np.float64)
+    n = nw.shape[0]
+    cap_w = np.where(valid & (nw >= 0), np.maximum(nw, 1.0), 0.0)
+    w_div = np.where(nw > 0, nw, 1.0)
+    k_total = float(sum(max(int(c), 0) for c in t.constraints))
+    rail = k_total * float(pw.sum()) / max(float(cap_w.sum()), 1.0)
+    ids = prev.reshape(prev.shape[0], -1)
+    m = ids >= 0
+    w_rep = np.broadcast_to(pw[:, None], ids.shape)
+    fill = np.bincount(ids[m].ravel(), weights=w_rep[m].ravel(),
+                       minlength=n)[:n]
+    bound = max(rail, float((fill / w_div).max()))
+    bound += float(stick.max())
+    return bound + float(np.maximum(-nw, 0.0).max())
+
+
+def band_tenant(P, N, seed, *, invalid=0, negative_weights=False,
+                stick=None, rules=RULES, empty=False):
+    """make_tenant with float weights over a wide range (so the sums
+    round), and the node, stickiness and prev variants the guard reads
+    (``empty``: an onboarding tenant, whose bound is the rail term)."""
+    t = make_tenant(P, N, seed, key=f"b{P}x{N}s{seed}")
+    if empty:
+        t = dataclasses.replace(t, prev=np.full_like(t.prev, -1))
+    rng = np.random.default_rng(seed + 1000)
+    pw = (rng.random(P) * 10.0 ** rng.uniform(-9, 1, P)).astype(np.float32)
+    nw = rng.uniform(0.5, 4.0, N).astype(np.float32)
+    if negative_weights:  # cbgt's booster: negative weights boost
+        nw[rng.choice(N, N // 3, replace=False)] *= -3
+    valid = np.ones(N, bool)
+    valid[rng.choice(N, invalid, replace=False)] = False
+    st = rng.uniform(0.5, 3.0, (P, 2)).astype(np.float32) if stick is None \
+        else np.full((P, 2), stick, np.float32)
+    return dataclasses.replace(
+        t, partition_weights=pw, node_weights=nw, valid_node=valid,
+        stickiness=st, rules=rules)
+
+
+# Each case holds several tenants; the ragged ones share one batch
+# class with different P and N (bucket_size 208 for P 193..208, 18 for
+# N 17..18), with enough partitions that their weight sums round
+# differently in another summation order; the empty ones (no seeded
+# fill, no stickiness) read that sum in their bound unrounded.
+TIER_BAND_CASES = {
+    "ragged": [band_tenant(p, n, seed=p + n, empty=n == 18,
+                           stick=0.0 if n == 18 else None)
+               for p in (193, 200, 208) for n in (17, 18)],
+    "invalid_nodes": [band_tenant(18, n, seed=n, invalid=5)
+                      for n in (17, 18)],
+    "negative_weights": [band_tenant(p, 17, seed=p, negative_weights=True)
+                         for p in (17, 18)],
+    "negative_stickiness": [band_tenant(p, 18, seed=p, stick=-2.5)
+                            for p in (17, 18)],
+    # Ids past the tenant's last node (or below -1) count toward no
+    # node, as in the scalar guard; they must not spill into the next
+    # tenant's nodes.
+    "stray_ids": [dataclasses.replace(
+        band_tenant(18, 17, seed=7),
+        prev=np.where(np.arange(18)[:, None, None] % 3 == 0,
+                      np.array([17, -4])[None, :, None],
+                      band_tenant(18, 17, seed=7).prev).astype(np.int32)),
+        band_tenant(17, 18, seed=8, empty=True, stick=0.0)],
+    "rule_less": [band_tenant(18, 17, seed=1, rules=((), ())),
+                  dataclasses.replace(make_tenant(5000, 2, seed=2),
+                                      rules=((), ())),
+                  band_tenant(17, 18, seed=3)],
+    # Unit weights: 5,000 and 4,600 partitions over 2 nodes put a
+    # node's fill past 0.45 * _RULE_TIER = 4,500.
+    "overflow": [make_tenant(5000, 2, seed=4), band_tenant(17, 18, seed=5),
+                 make_tenant(4600, 2, seed=6)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(TIER_BAND_CASES))
+def test_batched_tier_band_bound_equals_scalar(case):
+    """The batched bound (one pass per class over ragged tenants) is
+    the scalar guard's bound on each tenant's own arrays, bit for bit;
+    tier_band_refusals refuses exactly the tenants whose scalar guard
+    raises, with the same message, and rule-less tenants stay exempt."""
+    tenants = [_normalized(t) for t in TIER_BAND_CASES[case]]
+    by_class = {}
+    for t in tenants:
+        by_class.setdefault(batch_class_of(t), []).append(t)
+    if case == "ragged":
+        assert len(by_class) == 1
+    for k, group in by_class.items():
+        got = tensor_mod._tier_band_bounds(
+            [t.prev for t in group], [t.partition_weights for t in group],
+            [t.node_weights for t in group], [t.valid_node for t in group],
+            [t.stickiness for t in group], k.constraints)
+        want = np.array([scalar_tier_band_bound(t) for t in group])
+        assert got.dtype == np.float64
+        assert np.array_equal(got.view(np.int64), want.view(np.int64))
+    refused = tier_band_refusals(tenants, recorder=Recorder())
+    expected = {}
+    for i, t in enumerate(tenants):
+        tensor_mod._tier_scale_memo.clear()
+        try:
+            tensor_mod._check_tier_band_scale(
+                t.prev, t.partition_weights, t.node_weights, t.valid_node,
+                t.stickiness, t.constraints, t.rules)
+        except ValueError as e:
+            expected[i] = str(e)
+    assert {i: str(e) for i, e in refused.items()} == expected
+    if case == "overflow":
+        assert sorted(expected) == [0, 2]
+        assert "tier band" in expected[0]
+    if case == "rule_less":
+        assert scalar_tier_band_bound(tenants[1]) >= 4500  # yet exempt
+        assert expected == {}
+
+
+def _overflow_pair():
+    """A valid tenant and one whose tier-band bound overflows (5,000
+    partitions on 2 nodes, with a hierarchy rule)."""
+    good = make_tenant(17, 8, seed=96, key="good3")
+    bad = make_tenant(5000, 2, seed=95, key="band3")
+    return good, bad
+
+
+def test_service_tier_band_overflow_fails_alone():
+    """A tenant whose tier-band bound overflows fails its own future
+    with the guard's ValueError, counted once; the co-batched valid
+    tenant still solves."""
+    good, bad = _overflow_pair()
+    rec = Recorder()
+    sink = InMemorySink()
+    rec.add_sink(sink)
+
+    async def drive():
+        svc = PlanService(admission_window_s=0.05, recorder=rec)
+        await svc.start()
+        res = await asyncio.gather(svc.submit(good), svc.submit(bad),
+                                   return_exceptions=True)
+        await svc.stop()
+        return res
+
+    good_res, bad_res = _run(drive())
+    assert isinstance(bad_res, ValueError)
+    assert "tier band" in str(bad_res)
+    assert np.array_equal(good_res.assign, solve_sequential(good)[0])
+    assert rec.counters["fleet.validate_refused"] == 1
+    (span,) = sink.by_name("fleet.validate")
+    assert span.attrs == {"tenants": 2, "classes": 2, "refused": 1}
+
+
+def test_solve_fleet_refuses_tier_band_overflow():
+    """Called directly, solve_fleet still refuses the tenant, with the
+    single-problem guard's message, before any dispatch."""
+    good, bad = _overflow_pair()
+    rec = Recorder()
+    n = bad.prev.shape[0]
+    tensor_mod._tier_scale_memo.clear()
+    with pytest.raises(ValueError, match="tier band") as single:
+        tensor_mod._check_tier_band_scale(
+            bad.prev, bad.partition_weights, bad.node_weights,
+            bad.valid_node, bad.stickiness, bad.constraints, bad.rules)
+    with pytest.raises(ValueError, match="tier band") as fleet:
+        solve_fleet([good, bad], recorder=rec)
+    assert str(fleet.value) == str(single.value)
+    assert f"P={n}" in str(fleet.value)
+    assert rec.counters.get("fleet.batches", 0) == 0
+
+
+def test_service_batch_checks_each_tier_band_once(monkeypatch):
+    """One service batch computes each tenant's tier-band bound once —
+    in its fleet.validate pass, one call per class — not again inside
+    the solve."""
+    tenants = [make_tenant(17 + (i % 4), 8, seed=60 + i, key=f"once{i}")
+               for i in range(6)]
+    calls = []
+    real = fleet_mod._tier_band_bounds
+
+    def counting(prevs, *a, **kw):
+        calls.append(len(prevs))
+        return real(prevs, *a, **kw)
+
+    monkeypatch.setattr(fleet_mod, "_tier_band_bounds", counting)
+    rec = Recorder()
+    sink = InMemorySink()
+    rec.add_sink(sink)
+
+    async def drive():
+        svc = PlanService(admission_window_s=0.05, recorder=rec)
+        await svc.start()
+        res = await asyncio.gather(*[svc.submit(t) for t in tenants])
+        await svc.stop()
+        return res
+
+    results = _run(drive())
+    assert [r.key for r in results] == [t.key for t in tenants]
+    spans = sink.by_name("fleet.validate")
+    assert sum(sp.attrs["tenants"] for sp in spans) == len(tenants)
+    assert sum(calls) == len(tenants)
+    assert len(calls) == sum(sp.attrs["classes"] for sp in spans)
+    assert "fleet.validate_refused" not in rec.counters
 
 
 def test_carry_cache_max_entries_drops_churned_keys():

@@ -228,8 +228,8 @@ def test_phase_span_times_once():
 # -- the fleet tier: ServicePlanner over one PlanService -------------------
 
 
-FLEET_SPANS = ("fleet.encode", "fleet.dispatch", "fleet.launch",
-               "fleet.fetch", "fleet.decode")
+FLEET_SPANS = ("fleet.encode", "fleet.validate", "fleet.dispatch",
+               "fleet.launch", "fleet.fetch", "fleet.decode")
 
 
 def _fleet_event(rec, sizes, removed):
@@ -266,15 +266,19 @@ def _fleet_event(rec, sizes, removed):
 def test_fleet_event_opens_its_phase_spans():
     """One coalesced event over three indexes of each of two shape
     classes: fleet.encode and fleet.decode once per index, one
-    fleet.dispatch per class (with its engine) holding one fleet.launch
-    and one fleet.fetch, and three blocking reads per class."""
+    fleet.validate for the batch (the tier-band pass over both
+    classes), one fleet.dispatch per class (with its engine) holding
+    one fleet.launch and one fleet.fetch, and three blocking reads per
+    class."""
     rec = Recorder()
     sink = _fleet_event(rec, [16, 16, 16, 40, 40, 40], NODES[1])
     spans = [sp for sp in sink.spans if sp.name in FLEET_SPANS]
     count = {n: sum(sp.name == n for sp in spans) for n in FLEET_SPANS}
     assert count == {"fleet.encode": 6, "fleet.decode": 6,
-                     "fleet.dispatch": 2, "fleet.launch": 2,
-                     "fleet.fetch": 2}
+                     "fleet.validate": 1, "fleet.dispatch": 2,
+                     "fleet.launch": 2, "fleet.fetch": 2}
+    (validate,) = [sp for sp in spans if sp.name == "fleet.validate"]
+    assert validate.attrs == {"tenants": 6, "classes": 2, "refused": 0}
     dispatch = {sp.span_id: sp for sp in spans
                 if sp.name == "fleet.dispatch"}
     for sp in dispatch.values():
@@ -290,7 +294,7 @@ def test_fleet_event_opens_its_phase_spans():
 
 
 def test_fleet_phases_reach_the_profiler_trace(tmp_path):
-    """The five fleet spans are host annotations in the profiler's trace
+    """The six fleet spans are host annotations in the profiler's trace
     too, as many as the recorder opened."""
     _fleet_event(Recorder(), [16, 40], NODES[2])  # compile outside
     opts = jax.profiler.ProfileOptions()
@@ -302,3 +306,4 @@ def test_fleet_phases_reach_the_profiler_trace(tmp_path):
     want = {n: len(sink.by_name(n)) for n in FLEET_SPANS}
     assert {n: len(v) for n, v in events.items()} == want
     assert want["fleet.encode"] == 2 and want["fleet.fetch"] == 2
+    assert want["fleet.validate"] == 1
