@@ -251,9 +251,9 @@ def _measure_entry(entry: str, d: Dims, builder: _Builder) -> float:
 def measure_budget_table(
         classes: Optional[list[str]] = None) -> list[dict[str, object]]:
     """Measure every budgeted (entry, class) row; returns dicts with
-    entry/class/measured/budget/ok — the artifact bench.py embeds as
-    ``detail.membudget`` and the calibration workflow prints.  Rows
-    whose AOT compile raises carry ``error`` instead of ``measured``."""
+    entry/class/measured/budget/ok — what the calibration workflow
+    prints.  Rows whose AOT compile raises carry ``error`` instead of
+    ``measured``."""
     builders = _builders()
     rows: list[dict[str, object]] = []
     for ent in sorted(HBM_BUDGETS):

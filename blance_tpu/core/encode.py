@@ -100,7 +100,7 @@ def pad_problem_arrays(
            np.ndarray, np.ndarray]:
     """Pad one problem's solver arrays to (p_target, n_target), inertly.
 
-    THE bit-neutral padding recipe, shared by plan_next_map_tpu's
+    THE bit-neutral padding recipe, shared by plan_pipeline's
     shape-bucketed path and the fleet batch stacker (plan/fleet.py):
     pad partitions are weight-0 bidders (their assignments are sliced
     off by the caller) and pad nodes invalid (valid=False => zero

@@ -184,15 +184,6 @@ class PlanOptions:
     # auto_shortlist_k).  Raise it when plan.sparse.shortlist_exhausted
     # stays nonzero in steady state (docs/DESIGN.md "Sparse solve").
     sparse_k: Optional[int] = None
-    # Opt-in fused plan pipeline for the tpu backend: chain
-    # encode→solve→move-diff→decode-pack through ONE jitted,
-    # buffer-donated device dispatch (plan/tensor.plan_pipeline) instead
-    # of the staged encode/solve/decode phases.  The map is bit-identical
-    # to the staged path's; the move diff rides along on device (reach it
-    # via plan_pipeline or PlannerSession.replan_with_moves to actually
-    # consume it).  Off by default: it changes dispatch structure, and
-    # one-shot callers with custom hooks fall back anyway.
-    fused_pipeline: bool = False
 
     # --- validation ---
     # Post-solve constraint audit on the batched (tpu) backend: duplicates,

@@ -116,7 +116,7 @@ class ServicePlanner(CyclePlanner):
     off-protocol event (divergent pass, supersede, statics swap, shape
     drift, cache eviction) demotes to a full re-encode, never a stale
     map.  ``host_phase`` accumulates host wall-clock seconds per phase
-    (encode/decode) for the bench stage's phase split."""
+    (encode/decode), the planner's own phase split."""
 
     def __init__(self, key: str, service: PlanService, *,
                  recorder: Optional[Any] = None,
@@ -135,7 +135,7 @@ class ServicePlanner(CyclePlanner):
                                    tuple[int, ...], int, bytes,
                                    bytes]] = None
         # Host wall-clock per planner phase (perf_counter seconds; NOT
-        # recorder/virtual time — the bench phase-split source).
+        # recorder/virtual time — the host phase-split source).
         self.host_phase: dict[str, float] = {"encode": 0.0,
                                              "decode": 0.0}
 
@@ -232,8 +232,8 @@ class ServicePlanner(CyclePlanner):
             # states, degenerate shapes) full-encodes every cycle by
             # design, and counting those would break the attribution
             # bound (tenants <= encode_cold <= tenants + demotions +
-            # evictions) the perf-smoke gate pins.  Its full decodes
-            # still show as fleet.decode_full.
+            # evictions) tests/test_encode_resident.py pins.  Its full
+            # decodes still show as fleet.decode_full.
             rec.count("fleet.encode_cold")
             self._encodes.put(self.key, st)
         return problem, st
@@ -369,8 +369,8 @@ class FleetController:
     ``coalesce=False`` is the sequential loop-per-tenant BASELINE: the
     same code path with a zero admission window and ``max_batch=1``,
     so every tenant plan costs its own device dispatch — the
-    configuration the ``fleet_loop`` bench stage beats (identical
-    final maps, measurably fewer dispatches; docs/FLEET.md).
+    configuration coalescing beats (identical final maps, measurably
+    fewer dispatches; tests/test_fleet_loop.py, docs/FLEET.md).
 
     Shared state (analysis/race_lint.py SHARED_STATE): the tenant
     registry is mutated only from the driving task (``add_tenant`` /
@@ -673,7 +673,7 @@ class FleetController:
         across every tenant loop: ``encode``/``decode`` from the
         planners, ``device`` from the service's solve worker.  This is
         perf_counter time (not the virtual clock), so it is NOT part of
-        the replayable account — it is the bench phase-split source
+        the replayable account — it is the host phase-split source
         that makes the host-encode share visible (docs/FLEET.md)."""
         out = {"encode": 0.0, "decode": 0.0,
                "device": float(self.service.host_solve_s)}

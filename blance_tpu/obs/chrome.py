@@ -19,7 +19,7 @@ object format (the stable subset both viewers load):
   batch by batch — plus one final sample per counter at the trace end
   so the track closes at its end-of-run value.
 
-``trace(...)`` is the one-call wrapper (bench.py ``--trace-out`` uses it):
+``trace(...)`` is the one-call wrapper:
 it attaches the sink, runs the body under ``device_profile`` when a TPU log
 dir is given — both captures cover the same wall-clock window, so host
 spans and the TPU trace (opened side-by-side in Perfetto) line up — and

@@ -19,7 +19,7 @@ with no extra instrumentation in the orchestrator:
 - prediction error is scored ONLINE: at each update where an estimate
   already existed, the relative error ``|predicted - observed| /
   observed`` lands in the ``costmodel.rel_err`` histogram and the
-  calibration report (bench's costmodel stage publishes its p50);
+  calibration report (its p50 is the model's headline accuracy);
 - the whole model round-trips through JSON (:meth:`save` /
   :meth:`load`), so a scheduler can warm-start from the previous run's
   calibration instead of re-learning a fleet from scratch.
@@ -48,9 +48,11 @@ EXEC_SPAN = "orchestrate.move.exec"
 _FORMAT_VERSION = 1
 _PRIORS_VERSION = 1
 
-# The committed bench calibration: per-op EWMA aggregates measured by
-# bench.py's costmodel stage (regenerate from its ``op_priors_s``
-# output).  Seeding these as op-level priors means a scheduler on a
+# The committed calibration: per-op EWMA aggregates (``op_priors_s``)
+# measured on 2026-08-03 on a CPU host, from a 128x10 chaos rebalance
+# plus a full promote/demote swap leg on the heterogeneous-tier data
+# plane (its "source" field says the same).  Seeding these as op-level
+# priors means a scheduler on a
 # NEVER-OBSERVED cluster already prices a del cheaper than an add
 # instead of running uniform-cost (ISSUE 12 satellite).
 DEFAULT_PRIORS_PATH = os.path.join(os.path.dirname(__file__),
@@ -59,15 +61,16 @@ DEFAULT_PRIORS_PATH = os.path.join(os.path.dirname(__file__),
 
 def default_op_priors(path: Optional[str] = None) -> dict[str, float]:
     """Load the committed per-op prior table: op kind -> seconds.
-    Raises on a version mismatch (regenerate the file from the bench
-    costmodel stage) so a stale format can never silently mis-seed."""
+    Raises on a version mismatch (recalibrate the file from a
+    CostModel's op aggregates) so a stale format can never silently
+    mis-seed."""
     with open(path if path is not None else DEFAULT_PRIORS_PATH) as f:
         data = json.load(f)
     version = data.get("version")
     if version != _PRIORS_VERSION:
         raise ValueError(
             f"cost-model priors version {version!r} != {_PRIORS_VERSION}"
-            f" (regenerate the file from the bench costmodel stage)")
+            f" (recalibrate the file from a CostModel's op aggregates)")
     return {str(op): float(s)
             for op, s in data["op_priors_s"].items()}
 
@@ -155,7 +158,7 @@ class CostModel:
     def seed_priors(self, op_priors_s: "dict[str, float]",
                     n: int = 1) -> None:
         """Seed op-level fallback estimates (op kind -> seconds) for
-        ops with NO observations yet — the committed bench calibration
+        ops with NO observations yet — the committed calibration
         (``default_op_priors``) is the canonical source.  Live
         observations take over through the normal EWMA fold; aggregates
         that already learned from real spans are never overwritten."""
@@ -167,7 +170,7 @@ class CostModel:
     @classmethod
     def with_priors(cls, path: Optional[str] = None,
                     **kwargs: Any) -> "CostModel":
-        """A fresh model seeded from the committed bench calibration
+        """A fresh model seeded from the committed calibration
         file — the scheduler's cold-start spelling."""
         model = cls(**kwargs)
         model.seed_priors(default_op_priors(path))

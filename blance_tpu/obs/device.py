@@ -23,10 +23,10 @@ as everything else:
   AOT-compiled once more and XLA's own ``cost_analysis()`` /
   ``memory_analysis()`` are published as ``device.flops`` /
   ``device.hbm_bytes`` / ``device.peak_alloc_bytes`` gauges labeled
-  ``{entry=,klass=}``: the Prometheus endpoint and the bench artifact
-  then show exactly what each bucket class costs on device, per the
-  GSPMD argument (arXiv:2105.04663) that bucketed compilation is only a
-  win if retraces and per-class costs are actually measured.
+  ``{entry=,klass=}``: the Prometheus endpoint then shows exactly
+  what each bucket class costs on device, per the GSPMD argument
+  (arXiv:2105.04663) that bucketed compilation is only a win if
+  retraces and per-class costs are actually measured.
 - **Sweep-level convergence traces** (:func:`record_sweep_trace`).  The
   converged solve's fixpoint loop is fused into one device program, so
   per-sweep host spans cannot exist; instead the solver (with
@@ -39,9 +39,9 @@ as everything else:
 Everything is OFF by default: attribution contextvars are always set
 (they cost a token swap), but no logging handler is installed, no AOT
 compile runs, and no extra solver outputs exist until :func:`enable` —
-so the tier-1 recompile budgets and the timed bench loops see byte-for-
-byte identical behavior unless a caller opted in (bench stages, the CI
-``device-obs`` step, and the device-obs tests do).
+so the tier-1 recompile budgets and the timed benchmark loops see
+byte-for-byte identical behavior unless a caller opted in (the CI
+``device-obs`` step and the device-obs tests do).
 
 CLI (the CI step)::
 
@@ -162,12 +162,13 @@ class _Tap(logging.Handler):
 class CompileMonitor:
     """Process-wide XLA compile counter with entry attribution.
 
-    Use as a context manager around a stage (bench does) or install the
-    process-global one via :func:`enable`.  ``emit=True`` additionally
-    publishes every event to the CURRENT recorder
+    Use as a context manager around a stage (``chip_smoke.py``'s phases
+    do) or install the process-global one via :func:`enable`.
+    ``emit=True`` additionally publishes every event to the CURRENT
+    recorder
     (``device.compiles{entry=}`` counter, ``device.compile_s{entry=}``
-    histogram) — stage-local monitors keep ``emit=False`` so a bench
-    stage nested inside the global observatory never double-counts.
+    histogram) — stage-local monitors keep ``emit=False`` so a stage
+    nested inside the global observatory never double-counts.
 
     Counts are exact per attribution scope; thread-safe (compiles can
     happen on executor threads — the fleet service's solve path)."""
@@ -251,7 +252,7 @@ class CompileMonitor:
             return sum(self.by_entry.values())
 
     def summary(self) -> dict:
-        """JSON-ready stage summary (bench embeds this per stage)."""
+        """JSON-ready stage summary."""
         with self._lock:
             return {
                 "total": sum(self.by_entry.values()),

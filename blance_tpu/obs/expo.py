@@ -182,9 +182,6 @@ def default_registry() -> MetricsRegistry:
         Metric("plan.pipeline.warm", "counter",
                "pipeline dispatches resolved by the one-sweep warm "
                "repair (accepted through every gate)"),
-        Metric("plan.pipeline.fallback", "counter",
-               "pipeline dispatch failures degraded to the staged "
-               "encode/solve/decode path"),
         Metric("plan.pipeline.dispatch_s", "histogram",
                "wall-clock seconds per fused pipeline device dispatch "
                "(solve + diff + pack, one program)"),
@@ -388,7 +385,7 @@ def default_registry() -> MetricsRegistry:
         Metric("fleet.encode_patch_bytes", "counter",
                "array bytes written by resident encode delta patches — "
                "the warm cycle's whole fresh-data footprint (bounded "
-               "by dirty rows + scalars; the perf-smoke gate pins it)"),
+               "by dirty rows + scalars)"),
         Metric("fleet.decode_full", "counter",
                "full decode_assignment runs on the planner path (cold "
                "cycles, first decode after a cold encode, pass-through "

@@ -386,8 +386,7 @@ class Recorder:
 
     def summary(self) -> dict:
         """Everything aggregate, JSON-serializable: per-span-name totals,
-        counters, gauges, and histogram percentile summaries — the block
-        bench.py embeds into its artifact."""
+        counters, gauges, and histogram percentile summaries."""
         with self._lock:
             spans = {
                 name: {"total_s": self.span_totals[name],

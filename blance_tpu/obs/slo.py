@@ -84,8 +84,8 @@ class MoveObserver(Protocol):
 
 @dataclass
 class SloSummary:
-    """The end-of-run SLO snapshot (``RebalanceResult.slo``, the bench
-    artifact's ``slo`` block).  Formulas in docs/OBSERVABILITY.md."""
+    """The end-of-run SLO snapshot (``RebalanceResult.slo``).  Formulas
+    in docs/OBSERVABILITY.md."""
 
     availability: float
     churn_ratio: float

@@ -6,8 +6,9 @@ blocks.  The tile shape is a pure throughput knob — results are
 bit-identical across tiles — so it is tunable per deployment via
 environment variables, read ONCE at import: the values are jit-static,
 and changing them mid-process would silently recompile rather than
-retune.  ``bench.py --tile-sweep`` measures the candidates and emits the
-choice as a JSON artifact.
+retune.  A tile is chosen on the chip: run the benchmark
+(``benchmark/run.py``) with the ``BLANCE_*_TILE_*`` variables set, one
+candidate per process.
 """
 
 from __future__ import annotations

@@ -47,7 +47,7 @@ __all__ = ["min2_argmin", "min2_argmin_reference", "priced_min2_argmin",
 _INF = float("inf")
 
 # Default tile shape for the priced reduction, overridable for tuning
-# sweeps (bench.py --tile-sweep).  This kernel is the matrix engine's hot
+# sweeps (ops/_tiles.py).  This kernel is the matrix engine's hot
 # op in BOTH the cold fixpoint and the warm one-sweep repair, so the
 # sweep's tile choice feeds the delta-replan path too.  Read once at
 # import (jit-static; see ops/_tiles.py).

@@ -20,8 +20,8 @@ index wins within and across tiles.
 
 Correctness is pinned by tests/test_score_fused.py: interpret-mode runs
 of this kernel against the reference matrix formula, term order
-preserved; bench.py additionally verifies compiled-vs-matrix on a real
-device batch before enabling the fused path for timed runs.
+preserved; chip_smoke.py's kernels phase checks the compiled kernel
+against the interpreted one on a chip batch with ties.
 """
 
 from __future__ import annotations
@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 from ._tiles import tile_env
 
 # Tile shape for the score kernel, overridable for tuning sweeps
-# (bench.py --tile-sweep).  Read once at import: the values are
+# (ops/_tiles.py).  Read once at import: the values are
 # jit-static, so changing them mid-process would silently recompile
 # rather than retune.
 _TILE_P = tile_env("BLANCE_FUSED_TILE_P", 256, 8)

@@ -138,8 +138,8 @@ def sparse_priced_min2(
     """Fused (best, argmin, second, raw) over ``score + price``.
 
     Bit-identical to :func:`sparse_min2_reference` (pinned by
-    tests/test_sparse.py in interpret mode; bench.py verifies the
-    compiled kernel on device before timing the sparse stage).
+    tests/test_sparse.py in interpret mode; chip_smoke.py's kernels
+    phase checks the compiled kernel on the chip).
     """
     p, k = score.shape
     if k == 0:

@@ -404,7 +404,7 @@ class CriticalPathScheduler(SchedulerPolicy):
     whose ``predict_move`` prices every move — pass the one you attach
     to the live Recorder (``rec.add_sink(model)``) so estimates
     recalibrate online across passes; by default a fresh model seeded
-    with the committed per-op bench priors
+    with the committed per-op priors
     (``obs/costmodel_priors.json``), so even a never-observed cluster
     schedules on non-uniform costs.  ``device_threshold`` overrides
     when the rank sweep moves on-device (:mod:`.ranks`)."""

@@ -8,7 +8,8 @@
             + native/planner.cpp) — bit-identical results, ~100x throughput;
             falls back to "greedy" when unsupported hooks are in play.
 - "tpu":    the batched cost-tensor planner (plan/tensor.py) — whole-problem
-            scoring on device, constraint repair, sharded over partitions.
+            scoring on device, constraint repair, sharded over partitions;
+            one fused dispatch (plan_pipeline) per plan.
 - "auto":   "tpu" for large problems, "native" (or "greedy") otherwise.
 """
 
@@ -54,7 +55,8 @@ def plan_next_map(
     Returns (next_map, warnings) where warnings is keyed by partition name
     (constraint shortfalls degrade to warnings, never errors — reference
     plan.go:231-235).  ``timer`` (utils.trace.PhaseTimer) attributes
-    wall-clock to encode / solve / decode on the tpu backend.
+    wall-clock to the pipeline phases (encode, dispatch, validate,
+    decode) on the tpu backend.
     """
     if model is None:
         raise ValueError("model is required")
@@ -82,20 +84,6 @@ def plan_next_map(
                 prev_map, partitions_to_assign, nodes_all,
                 nodes_to_remove, nodes_to_add, model, opts)
         if backend == "tpu":
-            if opts.fused_pipeline:
-                # Fused fast path: one jitted encode→solve→diff→pack
-                # dispatch (plan/tensor.plan_pipeline); the map is
-                # bit-identical to the staged path's.  The on-device
-                # move diff rides along — callers that want it call
-                # plan_pipeline directly (this signature returns only
-                # (map, warnings)).
-                from .tensor import plan_pipeline  # deferred: imports jax
-
-                next_map, warnings, _ = plan_pipeline(
-                    prev_map, partitions_to_assign, nodes_all,
-                    nodes_to_remove, nodes_to_add, model, opts,
-                    timer=timer, want_moves=False)
-                return next_map, warnings
             from .tensor import plan_next_map_tpu  # deferred: imports jax
 
             return plan_next_map_tpu(

@@ -513,7 +513,7 @@ class EncodeCache:
       ``fleet.encode_demotions{reason=...}`` — so a fleet's cold
       re-encodes are exactly attributable: in steady state,
       ``fleet.encode_cold == first encodes + demotions + evictions``
-      (the bench ``fleet_loop`` stage gates that identity).
+      (tests/test_encode_resident.py pins that identity).
 
     Shared-state discipline (analysis/race_lint.py ``SHARED_STATE``):
     the cache is shared by N tenant control-loop tasks, but every

@@ -184,7 +184,7 @@ class PlanService:
         # Cumulative HOST wall-clock seconds spent inside the fleet
         # solve (single writer: the solve runs on the dispatcher
         # coroutine or the one-thread executor).  perf_counter time,
-        # not the recorder clock — the bench phase-split's "device"
+        # not the recorder clock — the host phase-split's "device"
         # share (fleet.dispatch_s is virtual under DeterministicLoop).
         self.host_solve_s = 0.0
         self._queue: "asyncio.Queue[object]" = \

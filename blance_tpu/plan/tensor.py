@@ -35,7 +35,6 @@ stay globally consistent while scores stay local.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 from functools import partial
 from typing import NamedTuple, Optional
@@ -2698,7 +2697,8 @@ def _pipeline_cold_impl(
     SolveCarry's tables, computed with the carry builder's exact ops so
     the packaged carry is bitwise what carry_from_assignment would build
     —  without a second dispatch.  The solve is the UNCHANGED converged
-    fixpoint trace, so ``assign`` is bit-identical to the staged path's.
+    fixpoint trace, so ``assign`` is bit-identical to
+    solve_dense_converged's.
     """
     from ..core.encode import pack_assignment_core
     from ..moves.batch import diff_assignments
@@ -2867,33 +2867,35 @@ def plan_pipeline(
     favor_min_nodes: bool = False,
     want_moves: bool = True,
 ):
-    """plan_next_map_tpu + the move diff in ONE device dispatch.
+    """Plan the next map and its move diff in ONE device dispatch: the
+    tpu backend of ``plan_next_map`` (through :func:`plan_next_map_tpu`).
 
-    Returns (next_map, warnings, moves): the map and warnings are
-    bit-identical to ``plan_next_map_tpu``'s, and ``moves`` matches
+    Returns (next_map, warnings, moves).  ``moves`` matches
     ``moves.batch.calc_all_moves(seeded_beg, next_map, model,
     favor_min_nodes)`` (the per-partition ordered op lists the
     orchestrator consumes), where seeded_beg resolves missing prev
     entries from partitions_to_assign exactly like the encoder.  The
-    encode stays host (string interning), then encode->solve->diff->
-    decode-pack run as one jitted, buffer-donated program — no
-    intermediate host transfer between solve and diff, and decode's
-    host share is only the id->name gather.
+    encode stays host (string interning), then solve->diff->decode-pack
+    run as one jitted, buffer-donated program — no intermediate host
+    transfer between solve and diff, and decode's host share is only
+    the id->name gather.  ``nodes_to_add`` is implicit (fresh nodes simply
+    have zero counts, which attracts load).
 
     Caveat shared with PlannerSession.moves(): partitions whose
     beginning state holds one node in several states diff through the
     dense one-state-per-node encoding (calc_all_moves's irregular-host
     fallback does not apply); the solver's own outputs never do that.
 
-    Engine/runtime failures degrade to the staged path
-    (plan_next_map_tpu + device diff), counted as
-    ``plan.pipeline.fallback`` — the pipeline is a fast path, never a
-    new failure mode.
+    A score engine that fails to compile or run retries once on the
+    other engine when the engine came from "auto"
+    (:func:`_with_engine_fallback`).  Custom placement hooks the device
+    score can't express run the native/greedy exact path instead — a
+    cbgt-style app keeps its policy even when ``backend="auto"`` routes
+    a large problem here.
 
-    ``want_moves=False`` skips the host move materialization (and the
-    fallback paths' diff entirely), returning ``{}`` as the third
-    element — for callers that only want the map riding the fused
-    dispatch (plan_next_map's ``fused_pipeline`` option)."""
+    ``want_moves=False`` skips the host move materialization, returning
+    ``{}`` as the third element (the device diff still runs inside the
+    same program)."""
     from ..moves.batch import calc_all_moves
     from ..utils.trace import PhaseTimer
 
@@ -2901,11 +2903,15 @@ def plan_pipeline(
     timer = timer if timer is not None else PhaseTimer()
     rec = get_recorder()
     if not _tpu_supported(opts):
-        # Exact-path fallback keeps custom placement hooks; the move
-        # diff still runs on device against the dense maps.
-        next_map, warnings = plan_next_map_tpu(
-            prev_map, partitions_to_assign, nodes_all, nodes_to_remove,
-            nodes_to_add, model, opts, timer=timer)
+        from .native import plan_next_map_native  # falls back to greedy
+
+        # The exact path has no encode/solve/decode split; attribute it
+        # all to "solve" so a caller's timer still sees the wall-clock.
+        with phase_span("plan.solve", timer=timer,
+                        engine="exact-fallback"):
+            next_map, warnings = plan_next_map_native(
+                prev_map, partitions_to_assign, nodes_all,
+                nodes_to_remove, nodes_to_add, model, opts)
         moves = calc_all_moves(
             _seeded_beg_map(prev_map, partitions_to_assign), next_map,
             model, favor_min_nodes) if want_moves else {}
@@ -2941,6 +2947,11 @@ def plan_pipeline(
             gids_a = problem.gids
             gv_a = problem.gid_valid
             solve_p, solve_n = problem.P, problem.N
+            # Opt-in static-shape bucketing: pad P and N up to the next
+            # bucket so repeated calls against a drifting cluster hit the
+            # jit cache instead of recompiling.  Pad partitions are
+            # weight-0 bidders (sliced off below) and pad nodes invalid,
+            # the inert-padding contract parallel/sharded.py relies on.
             if opts.shape_bucketing:
                 from ..core.encode import bucket_size, pad_problem_arrays
 
@@ -2956,50 +2967,39 @@ def plan_pipeline(
                                           rules)
             if not use_sparse:
                 check_dense_memory(solve_p, problem.S, solve_n, mode)
+            # Only under bucketing: p_real keeps the fill denominator at
+            # the REAL partition count while sizes drift within a bucket.
+            # Unbucketed solves keep total_p a compile-time constant — a
+            # traced scalar changes how XLA strength-reduces the fill
+            # division, and those low bits flip jitter-level ties (which
+            # is also why bucketed output is contract-equivalent to the
+            # unbucketed solve, not bit-identical).  device_put: the
+            # scalar reaches the device as an EXPLICIT transfer, which
+            # the tests' transfer guard permits.
             p_real = (jax.device_put(np.float32(problem.P))
                       if opts.shape_bucketing else None)
 
-        try:
-            if use_sparse:
-                res = _dispatch_pipeline_sparse(
-                    prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
-                    constraints, rules,
-                    max_iterations=max(int(opts.max_iterations), 1),
-                    shortlist_k=_opts_shortlist_k(
-                        opts, solve_n, constraints, rules),
-                    sparse_impl=resolve_sparse_impl(None),
-                    favor_min_nodes=favor_min_nodes,
-                    entry="sparse.pipeline", timer=timer, p_real=p_real)
-            else:
-                res = _dispatch_pipeline_cold(
-                    prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
-                    constraints, rules,
-                    max_iterations=max(int(opts.max_iterations), 1),
-                    fused_score=mode,
-                    allow_fallback=_FUSED_SCORE_DEFAULT == "auto",
-                    favor_min_nodes=favor_min_nodes,
-                    entry=("solve_dense.bucketed" if opts.shape_bucketing
-                           else "pipeline.cold"),
-                    timer=timer, p_real=p_real)
-        except (ValueError, TypeError):
-            raise  # deterministic input errors: same on the staged path
-        except Exception as e:
-            import warnings as _warnings
-
-            first = (str(e).splitlines() or [""])[0][:200]
-            _warnings.warn(
-                f"blance_tpu plan_pipeline: fused dispatch failed "
-                f"({type(e).__name__}: {first}); degrading to the staged "
-                f"path", UserWarning, stacklevel=2)
-            rec.count("plan.pipeline.fallback")
-            next_map, warnings = plan_next_map_tpu(
-                prev_map, partitions_to_assign, nodes_all,
-                nodes_to_remove, None, model, opts, timer=timer)
-            moves = calc_all_moves(
-                _seeded_beg_map(prev_map, partitions_to_assign),
-                next_map, model, favor_min_nodes) if want_moves else {}
-            return next_map, warnings, moves
-
+        if use_sparse:
+            res = _dispatch_pipeline_sparse(
+                prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
+                constraints, rules,
+                max_iterations=max(int(opts.max_iterations), 1),
+                shortlist_k=_opts_shortlist_k(
+                    opts, solve_n, constraints, rules),
+                sparse_impl=resolve_sparse_impl(None),
+                favor_min_nodes=favor_min_nodes,
+                entry="sparse.pipeline", timer=timer, p_real=p_real)
+        else:
+            res = _dispatch_pipeline_cold(
+                prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a,
+                constraints, rules,
+                max_iterations=max(int(opts.max_iterations), 1),
+                fused_score=mode,
+                allow_fallback=_FUSED_SCORE_DEFAULT == "auto",
+                favor_min_nodes=favor_min_nodes,
+                entry=("solve_dense.bucketed" if opts.shape_bucketing
+                       else "pipeline.cold"),
+                timer=timer, p_real=p_real)
         assign, _sweeps, _carry, (d_nodes, d_states, d_ops), \
             (packed, counts) = res
         assign = assign[:problem.P]
@@ -3047,16 +3047,19 @@ def _dispatch_pipeline_cold(
     fused_score: str, allow_fallback: bool, favor_min_nodes: bool,
     entry: str, timer=None, carry_used=None, p_real=None, donate=True,
 ):
-    """One cold pipeline dispatch with the engine-failure degradation of
-    solve_converged_resilient (retry once on the opposite engine when
-    the mode came from "auto").  Returns (assign_np, sweeps,
-    SolveCarry, (d_nodes, d_states, d_ops) np, (packed, counts) np) —
-    everything off-device in one host wait, at the end."""
-    import warnings as _warnings
-
+    """One cold pipeline dispatch, retried once on the other score
+    engine when ``allow_fallback`` (:func:`_with_engine_fallback`).
+    Returns (assign_np, sweeps, SolveCarry, (d_nodes, d_states, d_ops)
+    np, (packed, counts) np) — everything off-device in one host wait,
+    at the end."""
     rec = get_recorder()
 
     def run(m: str):
+        if m != fused_score:
+            # A retry onto the matrix engine passes the memory guard the
+            # caller ran for the first engine.
+            check_dense_memory(prev_a.shape[0], prev_a.shape[1],
+                               nw_a.shape[-1], m)
         impl = _pipeline_cold_donating if donate else _pipeline_cold_jit
         t0 = rec.now()
         with phase_span("plan.pipeline.dispatch", timer=timer,
@@ -3085,24 +3088,10 @@ def _dispatch_pipeline_cold(
             return (assign_np, sweeps_np, carry, tuple(darrs),
                     (packed_np, counts_np))
 
-    try:
-        return run(fused_score)
-    except (ValueError, TypeError):
-        raise
-    except Exception as e:
-        alt = {"off": "on", "on": "off"}.get(fused_score)
-        if not allow_fallback or alt is None or \
-                (alt == "on" and not pallas_available()):
-            raise
-        first = (str(e).splitlines() or [""])[0][:200]
-        _warnings.warn(
-            f"blance_tpu plan_pipeline: score engine {fused_score!r} "
-            f"failed to compile/run ({type(e).__name__}: {first}); "
-            f"retrying with {alt!r}", UserWarning, stacklevel=3)
-        rec.count("plan.engine_fallback")
-        if timer is not None:
-            timer.annotate("engine_fallback", f"-> {alt}")
-        return run(alt)
+    out, _mode = _with_engine_fallback(
+        run, fused_score, allow_fallback=allow_fallback,
+        context="plan_pipeline", timer=timer)
+    return out
 
 
 def _sparse_selected(opts: PlanOptions, p: int, s: int, n: int,
@@ -3178,6 +3167,8 @@ def _dispatch_pipeline_sparse(
             host, _host_shapes(prev_a.shape) + (prev_a.shape[:1],))
     rec.observe("plan.pipeline.dispatch_s", rec.now() - t0)
     rec.set_gauge("plan.sparse.k_effective", float(shortlist_k))
+    (timer.annotate if timer is not None else rec.set_attr)(
+        "engine", "sparse")
     patched, replaced = _apply_sparse_fallback(
         assign_np, exh_np, prev_fb, pw_a, nw_a, valid_a, stick_a,
         gids_a, gv_a, constraints, rules)
@@ -3214,23 +3205,11 @@ def solve_converged_resilient(
     allow_fallback: bool, context: str, timer=None,
     carry_used=None, return_carry: bool = False, p_real=None,
 ):
-    """solve_dense_converged with engine-failure degradation.
-
-    The auto-selected engine is a prediction from a working-set model
-    (_MATRIX_BYTES_PER_CELL / _HBM_BUDGET_FRACTION, calibrated on one
-    chip generation); when the prediction is wrong the matrix engine can
-    die in compile (HBM over-subscription) — or, more rarely, a fused
-    kernel can hit a Mosaic lowering gap on a new toolchain.  With
-    ``allow_fallback`` (set iff the mode came from "auto", never for an
-    explicit user choice) a failed engine retries once on the opposite
-    one, surfacing the switch as a UserWarning and on the timer's
-    annotations — so production callers degrade exactly like bench.py
-    does, instead of erroring.  Returns (assignment, engine-that-ran),
-    plus the rebuilt :class:`SolveCarry` when ``return_carry`` is set.
-    ``carry_used`` seeds the first sweep (see solve_dense_converged).
-    """
-    import warnings as _warnings
-
+    """solve_dense_converged with engine-failure degradation
+    (:func:`_with_engine_fallback`).  Returns (assignment,
+    engine-that-ran), plus the rebuilt :class:`SolveCarry` when
+    ``return_carry`` is set.  ``carry_used`` seeds the first sweep (see
+    solve_dense_converged)."""
     rec = get_recorder()
 
     def run(m: str) -> NPArray:
@@ -3249,6 +3228,36 @@ def solve_converged_resilient(
                 max_iterations=max_iterations, fused_score=m,
                 carry_used=carry_used, p_real=p_real))
 
+    out, mode = _with_engine_fallback(
+        run, mode, allow_fallback=allow_fallback, context=context,
+        timer=timer)
+    if return_carry:
+        return out, mode, carry_from_assignment(out, pweights, nweights)
+    return out, mode
+
+
+def _with_engine_fallback(run, mode: str, *, allow_fallback: bool,
+                          context: str, timer=None):
+    """``run(mode)``, retried once on the opposite score engine.
+
+    The auto-selected engine is a prediction from a working-set model
+    (_MATRIX_BYTES_PER_CELL / _HBM_BUDGET_FRACTION, calibrated on one
+    chip generation); when the prediction is wrong the matrix engine can
+    die in compile (HBM over-subscription) — or, more rarely, a fused
+    kernel can hit a Mosaic lowering gap on a new toolchain.  With
+    ``allow_fallback`` (set iff the mode came from "auto", never for an
+    explicit user choice) a failed engine retries once on the opposite
+    one, surfacing the switch as a UserWarning, the
+    ``plan.engine_fallback`` counter and an ``engine_fallback``
+    annotation.  The engine that ran is annotated as ``engine`` either
+    way.  Returns (run's result, the mode that ran)."""
+    import warnings as _warnings
+
+    rec = get_recorder()
+    # timer.annotate forwards to rec.set_attr (PhaseTimer is a shim over
+    # the Recorder), so write directly only when there is no timer —
+    # never both.
+    annotate = timer.annotate if timer is not None else rec.set_attr
     try:
         out = run(mode)
     except (ValueError, TypeError):
@@ -3266,25 +3275,13 @@ def solve_converged_resilient(
         _warnings.warn(
             f"blance_tpu {context}: score engine {mode!r} failed to "
             f"compile/run ({type(e).__name__}: {first}); retrying with "
-            f"{alt!r}", UserWarning, stacklevel=3)
+            f"{alt!r}", UserWarning, stacklevel=4)
         rec.count("plan.engine_fallback")
         out = run(alt)
         mode = alt
-        # timer.annotate forwards to rec.set_attr (PhaseTimer is a shim
-        # over the Recorder), so write directly only when there is no
-        # timer — never both.
-        if timer is not None:
-            timer.annotate("engine_fallback", f"-> {alt}")
-        else:
-            rec.set_attr("engine_fallback", f"-> {alt}")
-    engine = {"off": "matrix", "on": "fused",
-              "interpret": "fused-interpret"}[mode]
-    if timer is not None:
-        timer.annotate("engine", engine)
-    else:
-        rec.set_attr("engine", engine)
-    if return_carry:
-        return out, mode, carry_from_assignment(out, pweights, nweights)
+        annotate("engine_fallback", f"-> {alt}")
+    annotate("engine", {"off": "matrix", "on": "fused",
+                        "interpret": "fused-interpret"}[mode])
     return out, mode
 
 
@@ -3681,133 +3678,11 @@ def plan_next_map_tpu(
     timer=None,
 ) -> tuple[PartitionMap, dict[str, list[str]]]:
     """TPU-backed equivalent of plan_next_map_greedy: one global batched
-    solve instead of a sequential pass.  Same inputs/outputs; nodes_to_add
-    is implicit (fresh nodes simply have zero counts, which attracts load).
-    ``timer`` (utils.trace.PhaseTimer) attributes wall-clock to
-    encode / solve / decode when provided.
-
-    Custom placement hooks the device score can't express fall back to the
-    native/greedy exact path — a cbgt-style app keeps its policy even when
-    ``backend="auto"`` routes a large problem here."""
-    from ..utils.trace import PhaseTimer
-
-    opts = opts or PlanOptions()
-    timer = timer if timer is not None else PhaseTimer()
-    if not _tpu_supported(opts):
-        from .native import plan_next_map_native  # falls back to greedy
-
-        # The exact path has no encode/solve/decode split; attribute it
-        # all to "solve" so a caller's timer still sees the wall-clock.
-        with phase_span("plan.solve", timer=timer,
-                        engine="exact-fallback"):
-            return plan_next_map_native(
-                prev_map, partitions_to_assign, nodes_all,
-                nodes_to_remove, nodes_to_add, model, opts)
-    del nodes_to_add
-
-    with phase_span("plan.encode", timer=timer):
-        problem = encode_problem(
-            prev_map, partitions_to_assign, nodes_all, nodes_to_remove,
-            model, opts)
-    if problem.P == 0 or problem.N == 0 or problem.S == 0:
-        return decode_assignment(
-            problem,
-            np.full((problem.P, problem.S, max(problem.R, 1)), -1, np.int32),
-            partitions_to_assign, nodes_to_remove)
-
-    rules = tuple(
-        tuple(problem.rules.get(si, ())) for si in range(problem.S))
-    constraints = tuple(int(c) for c in problem.constraints)
-
-    # Opt-in static-shape bucketing (PlanOptions.shape_bucketing): pad
-    # P and N up to the next bucket so repeated pure-path calls against a
-    # drifting cluster hit the jit cache instead of recompiling — keeping
-    # shapes static is what makes repeated invocation cheap (GSPMD,
-    # arXiv:2105.04663).  Pad partitions are weight-0 bidders (their
-    # assignments are sliced off below) and pad nodes invalid
-    # (valid=False => zero capacity, +INF score, gid_valid=False), the
-    # same inert-padding contract parallel/sharded.py relies on, so the
-    # real rows solve identically to the unpadded problem.
-    prev_a = problem.prev
-    pw_a = problem.partition_weights
-    nw_a = problem.node_weights
-    valid_a = problem.valid_node
-    stick_a = problem.stickiness
-    gids_a = problem.gids
-    gv_a = problem.gid_valid
-    solve_p, solve_n = problem.P, problem.N
-    if opts.shape_bucketing:
-        from ..core.encode import bucket_size, pad_problem_arrays
-
-        solve_p = bucket_size(problem.P)
-        solve_n = bucket_size(problem.N)
-        (prev_a, pw_a, nw_a, valid_a, stick_a, gids_a, gv_a) = \
-            pad_problem_arrays(prev_a, pw_a, nw_a, valid_a, stick_a,
-                               gids_a, gv_a, solve_p, solve_n)
-
-    # Observatory attribution: the bucketed pure path owns its compiles
-    # as "solve_dense.bucketed" (first-wins, so the inner cold/carry
-    # labels inside solve_dense_converged don't re-claim them); the
-    # unbucketed path lets the inner labels stand.
-    obs_entry = _device.entry("solve_dense.bucketed") \
-        if opts.shape_bucketing else contextlib.nullcontext()
-    use_sparse = _sparse_selected(opts, solve_p, problem.S, solve_n,
-                                  rules)
-    with phase_span("plan.solve", timer=timer,
-                    partitions=problem.P, nodes=problem.N,
-                    engine=("sparse" if use_sparse else None),
-                    bucketed_shape=((solve_p, solve_n)
-                                    if opts.shape_bucketing else None)), \
-            obs_entry:
-        if use_sparse:
-            assign = solve_sparse(
-                jnp.asarray(prev_a), jnp.asarray(pw_a),
-                jnp.asarray(nw_a), jnp.asarray(valid_a),
-                jnp.asarray(stick_a), jnp.asarray(gids_a),
-                jnp.asarray(gv_a), constraints, rules,
-                k=_opts_shortlist_k(opts, solve_n, constraints, rules),
-                max_iterations=max(int(opts.max_iterations), 1),
-                p_real=(jax.device_put(np.float32(problem.P))
-                        if opts.shape_bucketing else None))
-            if timer is not None:
-                timer.annotate("engine", "sparse")
-        else:
-            assign, _engine = solve_converged_resilient(
-                jnp.asarray(prev_a),
-                jnp.asarray(pw_a),
-                jnp.asarray(nw_a),
-                jnp.asarray(valid_a),
-                jnp.asarray(stick_a),
-                jnp.asarray(gids_a),
-                jnp.asarray(gv_a),
-                constraints,
-                rules,
-                max_iterations=max(int(opts.max_iterations), 1),
-                mode=resolve_default_fused_score(solve_p, solve_n),
-                allow_fallback=_FUSED_SCORE_DEFAULT == "auto",
-                context="plan_next_map_tpu",
-                timer=timer,
-                # Only under bucketing: p_real keeps the fill
-                # denominator at the REAL partition count while sizes
-                # drift within a bucket.  Unbucketed solves keep total_p
-                # as a compile-time constant — a traced scalar changes
-                # how XLA strength-reduces the fill division, and those
-                # low bits flip jitter-level ties, perturbing the pinned
-                # fuzz contract for zero benefit on the default path.
-                # (This is also why bucketed output is
-                # contract-equivalent to the unbucketed solve, not
-                # bit-identical.)
-                # device_put: the traced scalar must reach the device as
-                # an EXPLICIT transfer (a bare np scalar operand rides
-                # the eager convert primitive, which the tier-1
-                # transfer-guard fixture in tests/conftest.py rejects as
-                # an implicit sync).
-                p_real=(jax.device_put(np.float32(problem.P))
-                        if opts.shape_bucketing else None),
-            )
-    assign = assign[:problem.P]  # bucketing pad rows are not real work
-    maybe_validate(problem, assign, opts.validate_assignment,
-                   "plan_next_map_tpu")
-    with phase_span("plan.decode", timer=timer):
-        return decode_assignment(
-            problem, assign, partitions_to_assign, nodes_to_remove)
+    solve instead of a sequential pass, through :func:`plan_pipeline`
+    (its move lists are not materialized).  Same inputs/outputs.
+    ``timer`` (utils.trace.PhaseTimer) attributes wall-clock to the
+    pipeline's phases when provided."""
+    next_map, warnings, _ = plan_pipeline(
+        prev_map, partitions_to_assign, nodes_all, nodes_to_remove,
+        nodes_to_add, model, opts, timer=timer, want_moves=False)
+    return next_map, warnings

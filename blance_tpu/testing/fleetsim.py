@@ -25,8 +25,8 @@ Per-element fleet solves are bit-identical to single-problem solves
 (plan/fleet.py's contract) and, with an unbounded carry cache, both
 modes make identical warm/cold decisions — so the two runs converge to
 IDENTICAL final maps with EQUAL executed moves, and the only deltas are
-the dispatch count and the wall-clock (the ``fleet_loop`` bench stage's
-gate).
+the dispatch count and the wall-clock (tests/test_fleet_loop.py pins
+the maps, moves and dispatch counts).
 
 Event-log schema (``FLEET_LOG_VERSION``): ``init`` (nodes + tenant
 specs + t0 placements), ``onboard`` (a staggered tenant's empty-start),

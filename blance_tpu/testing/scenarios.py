@@ -558,9 +558,9 @@ def fleet_zone_outage(seed: int = 5, tenants: int = 8,
     at once — N coalesced converge cycles through a handful of fleet
     dispatches — then return; two tenants heat up afterwards.
 
-    ``partitions`` overrides the tenant-size choice set (bench's
-    encode-residency A/B uses bigger tenants so the host-encode share
-    is visible); the default reproduces the committed traces."""
+    ``partitions`` overrides the tenant-size choice set (bigger
+    tenants make the encode-residency patch traffic visible); the
+    default reproduces the committed traces."""
     rng = random.Random(f"fzone:{seed}:{tenants}")
     nodes = _zone_nodes(3, 4)
     z1 = tuple(n for n in nodes if n.startswith("z1"))

@@ -188,7 +188,7 @@ async def _sim_main(scn: SimScenario, loop: DeterministicLoop,
         max_concurrent_partition_moves_per_node=scn.max_concurrent_moves)
     if scn.scheduler == "critical_path":
         # Critical-path move order (docs/SCHEDULER.md): the cost model
-        # seeds from the committed bench priors and recalibrates ONLINE
+        # seeds from the committed per-op priors and recalibrates ONLINE
         # from this very run's move spans (virtual-time durations, so
         # the whole account replays bit-identically); each controller
         # pass re-binds the policy against its fresh move plans.

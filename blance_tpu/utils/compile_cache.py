@@ -1,7 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point that compiles (``bench.py``,
-``chip_smoke.py``): ``JAX_COMPILATION_CACHE_DIR`` when the environment
+``chip_smoke.py`` uses ``JAX_COMPILATION_CACHE_DIR`` when the environment
 sets it — JAX reads that variable itself, so no other directory is set
 in code — and otherwise the fixed ``<repo>/.jax_cache``.  A fixed path
 matters: a cache directory named from a temp dir, a pid or the time

@@ -32,7 +32,7 @@ class PhaseTimer:
 
     ``annotations`` carries non-timing facts a caller wants surfaced with
     the timing report — e.g. which score engine the solve actually ran
-    after auto-selection/fallback (tensor.solve_converged_resilient)."""
+    after auto-selection/fallback (tensor._with_engine_fallback)."""
 
     totals: dict[str, float] = field(default_factory=dict)
     counts: dict[str, int] = field(default_factory=dict)

@@ -5,18 +5,18 @@
                                        # the one-chip solve they are
                                        # compared with, nothing else
 
-The deployment is BASELINE.json's north star as bench.py builds it
-(``bench._make_map`` / ``bench._rack_opts``): 100,000 partitions x
-10,000 nodes, primary + 1 replica, racks of 25 nodes with the rule
-"replica on another rack in the zone", and a warm previous map with 5%
-of the nodes removed.
+The deployment is BASELINE.json's north star (``_make_map`` /
+``_rack_opts``): 100,000 partitions x 10,000 nodes, primary + 1
+replica, racks of 25 nodes with the rule "replica on another rack in
+the zone", and a warm previous map with 5% of the nodes removed.
 
 One-chip phases, in one process:
 
 1. rebalance — ``bt.rebalance(..., backend="tpu")`` against an in-memory
    data plane: plan (sparse engine), diff, orchestrate every move;
-2. pipeline — ``plan_pipeline`` bit-equal to ``plan_next_map_tpu`` +
-   ``calc_all_moves``;
+2. pipeline — ``plan_pipeline`` bit-equal to the bare solver of the
+   engine it selects (its own dispatch, a host read, the host decode)
+   + ``calc_all_moves``;
 3. dense — the dense engine (``PlanOptions(sparse=False)``) at the same
    size, which resolves to the in-kernel ``score_fused`` engine;
 4. kernels — each compiled Pallas kernel against its XLA reference on a
@@ -44,7 +44,7 @@ import numpy as np
 P_FULL, N_FULL = 100_000, 10_000
 CLEAN = {"duplicates": 0, "on_removed_nodes": 0,
          "unfilled_feasible_slots": 0, "hierarchy_misses": 0}
-FALLBACK_COUNTERS = ("plan.engine_fallback", "plan.pipeline.fallback")
+FALLBACK_COUNTERS = ("plan.engine_fallback",)
 
 
 class SmokeFailure(Exception):
@@ -83,14 +83,38 @@ class Deployment:
                                  self.constraints, self.rules)
 
 
+def _make_map(P, N, seed=0):
+    """PartitionMap, node list and the 5% of nodes removed, seeded."""
+    from blance_tpu import Partition
+
+    rng = np.random.default_rng(seed)
+    nodes = [f"n{i:05d}" for i in range(N)]
+    removed = [nodes[i] for i in
+               rng.choice(N, N // 20, replace=False)]
+    p_ids = rng.integers(0, N, P)
+    r_ids = (p_ids + 1 + rng.integers(0, N - 1, P)) % N
+    prev = {str(i): Partition(str(i), {"primary": [nodes[p_ids[i]]],
+                                       "replica": [nodes[r_ids[i]]]})
+            for i in range(P)}
+    return prev, nodes, removed
+
+
+def _rack_opts(nodes):
+    from blance_tpu import HierarchyRule, PlanOptions
+
+    hier = {n: f"r{i // 25}" for i, n in enumerate(nodes)}
+    hier.update({f"r{i}": "z0" for i in range((len(nodes) + 24) // 25)})
+    return PlanOptions(node_hierarchy=hier,
+                       hierarchy_rules={"replica": [HierarchyRule(2, 1)]})
+
+
 def deployment(P: int, N: int) -> Deployment:
-    import bench
     from blance_tpu import model
     from blance_tpu.core.encode import encode_problem
 
-    prev, nodes, removed = bench._make_map(P, N)
+    prev, nodes, removed = _make_map(P, N)
     m = model(primary=(0, 1), replica=(1, 1))
-    opts = bench._rack_opts(nodes)
+    opts = _rack_opts(nodes)
     problem = encode_problem(prev, prev, nodes, removed, m, opts)
     return Deployment(prev, nodes, removed, m, opts, problem)
 
@@ -220,18 +244,34 @@ def phase_rebalance(dep: Deployment, require_chip: bool) -> None:
 
 
 def phase_pipeline(dep: Deployment) -> None:
-    from blance_tpu.moves.batch import calc_all_moves
-    from blance_tpu.plan.tensor import plan_next_map_tpu, plan_pipeline
+    import jax.numpy as jnp
 
+    from blance_tpu.core.encode import decode_assignment
+    from blance_tpu.moves.batch import calc_all_moves
+    from blance_tpu.plan.tensor import (_sparse_selected, plan_pipeline,
+                                        resolve_default_fused_score,
+                                        solve_dense_converged,
+                                        solve_sparse)
+
+    prob = dep.problem
+    arrays = (prob.prev, prob.partition_weights, prob.node_weights,
+              prob.valid_node, prob.stickiness, prob.gids, prob.gid_valid)
     with Phase("pipeline") as ph:
         f_map, f_warn, f_moves = plan_pipeline(
             dep.prev, dep.prev, dep.nodes, dep.removed, [], dep.model,
             dep.opts)
-        s_map, s_warn = plan_next_map_tpu(
-            dep.prev, dep.prev, dep.nodes, dep.removed, [], dep.model,
-            dep.opts)
+        if _sparse_selected(dep.opts, prob.P, prob.S, prob.N, dep.rules):
+            assign = solve_sparse(*arrays, dep.constraints, dep.rules,
+                                  k=dep.k)
+        else:
+            assign = solve_dense_converged(
+                *(jnp.asarray(a) for a in arrays), dep.constraints,
+                dep.rules,
+                fused_score=resolve_default_fused_score(prob.P, prob.N))
+        s_map, s_warn = decode_assignment(prob, np.asarray(assign),
+                                          dep.prev, dep.removed)
         s_moves = calc_all_moves(dep.prev, s_map, dep.model)
-        check(f_map == s_map, "pipeline map != plan_next_map_tpu map")
+        check(f_map == s_map, "pipeline map != the bare solver's map")
         check(f_warn == s_warn, "pipeline warnings differ")
         check(f_moves == s_moves, "pipeline moves != calc_all_moves")
         ph.fields.update(moves=count_moves(f_moves),

@@ -3,7 +3,7 @@
 Forces JAX onto the host CPU platform with 8 virtual devices BEFORE jax is
 imported anywhere, so multi-chip sharding tests (jax.sharding.Mesh over 8
 devices) run on machines with no TPU attached.  Real-TPU benchmarking happens
-in bench.py, not in the test suite.
+in benchmark/ (BENCHMARK.json), not in the test suite.
 """
 
 import os

@@ -794,6 +794,9 @@ def test_cli_stale_baseline_warns_by_default_fails_under_ci(tmp_path,
     out = capsys.readouterr().out
     assert rc == 1
     assert "ERROR: stale baseline entry" in out and "FAIL" in out
+    # The same run measured every HBM budget row of the smoke class:
+    # each AOT peak sits under its ceiling.
+    assert "MEM001" not in out
 
 
 def test_cli_json_output(tmp_path, capsys):

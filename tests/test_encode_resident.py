@@ -40,7 +40,7 @@ from blance_tpu.obs import Recorder, use_recorder
 from blance_tpu.plan.carry import EncodeCache
 from blance_tpu.plan.service import PlanService
 from blance_tpu.rebalance import ClusterDelta, _strip_nodes
-from blance_tpu.testing.fleetsim import run_fleet_scenario
+from blance_tpu.testing.fleetsim import run_fleet_scenario, tenant_model
 from blance_tpu.testing.scenarios import (
     fleet_noisy_neighbor,
     fleet_onboarding,
@@ -553,6 +553,23 @@ def test_residency_is_pure_perf_through_the_fleet(family, kw):
     # beyond the attributable colds.
     assert on.decode_full == on.encode_cold
     assert on.decode_patch == on.encode_warm
+
+
+def test_warm_patch_bytes_bounded_by_patched_rows():
+    """A warm cycle uploads O(delta): the patch bytes of the resident
+    run stay within the patched-row count times one row's ceiling (a
+    strip/adopt row's prev scatter and counts, plus its weight row) and
+    a per-warm-cycle scalar slack for node-add columns and dark-set
+    flips."""
+    scn = fleet_zone_outage(seed=5, tenants=8, partitions=(48, 64))
+    res = run_fleet_scenario(scn)
+    mdl = tenant_model(scn.tenants[0])
+    s = len(mdl)
+    r = max(st.constraints for st in mdl.values())
+    row_cap = s * r * 4 + s * 8 + 4 + 4 * s
+    assert res.encode_warm > 0 and res.encode_patch_rows > 0
+    assert res.encode_patch_bytes <= \
+        res.encode_patch_rows * row_cap + 256 * res.encode_warm
 
 
 def test_supersede_divergence_demotes_and_recovers():

@@ -4,7 +4,7 @@ The seam exists so host-phase timing (PhaseTimer, solver host seconds,
 simulator wall_s) flows through ONE declared clock boundary instead of
 scattered ``time.perf_counter()`` calls — the determinism lint's
 CLOCK_SEAMS contract.  These tests pin both halves: the default clock
-is the real perf counter (bench-reported numbers unchanged), and an
+is the real perf counter (reported numbers unchanged), and an
 injected clock is honored exactly (host-phase accounting itself is
 testable deterministically)."""
 

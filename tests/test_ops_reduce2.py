@@ -2,8 +2,8 @@
 
 Runs the kernel in interpret mode (tests run on the CPU platform,
 tests/conftest.py); compiled-mode parity on a real chip is exercised by
-bench.py.  The contract under test is the one the auction loop
-(blance_tpu/plan/tensor.py) depends on:
+chip_smoke.py's kernels phase.  The contract under test is the one the
+auction loop (blance_tpu/plan/tensor.py) depends on:
 
 - argmin ties break to the lowest index (determinism of the planner);
 - ``second`` masks the argmin POSITION, so duplicate minima at different

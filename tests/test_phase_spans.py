@@ -103,7 +103,7 @@ def test_host_steps_land_in_their_spans(prev, monkeypatch):
     assert set(spans) == set(PHASES)
     assert len(sink.spans) == len(PHASES)  # each phase once per call
     top = spans["plan.pipeline"]
-    assert top.attrs == {"partitions": P, "nodes": N}
+    assert top.attrs == {"partitions": P, "nodes": N, "engine": "matrix"}
     launch, dispatch = spans["plan.pipeline.launch"], \
         spans["plan.pipeline.dispatch"]
     assert launch.parent_id == dispatch.span_id

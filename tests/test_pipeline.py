@@ -6,8 +6,10 @@ Contracts pinned here:
   to decode_assignment's numpy pack, and the device prev scatter
   (prev_from_entries) to encode_problem's host fill;
 - plan_pipeline produces a bit-identical map, equal warnings AND equal
-  move lists vs the staged path (plan_next_map_tpu + calc_all_moves),
-  cold and bucketed, rules and rule-free;
+  move lists vs the unfused solver it fuses (encode_problem ->
+  solve_dense_converged -> host read -> decode_assignment, plus
+  calc_all_moves), cold and bucketed, rules and rule-free, and
+  plan_next_map(backend="tpu") returns plan_pipeline's map;
 - PlannerSession.replan_with_moves ≡ replan() followed by moves(), cold
   AND warm, single-device and mesh-sharded, with the same carry/counter
   semantics;
@@ -34,8 +36,11 @@ import jax.numpy as jnp
 
 from blance_tpu import HierarchyRule, Partition, PlanOptions, model
 from blance_tpu.core.encode import (
+    bucket_size,
+    decode_assignment,
     encode_problem,
     pack_assignment,
+    pad_problem_arrays,
     prev_from_entries,
 )
 from blance_tpu.moves.batch import calc_all_moves
@@ -56,7 +61,6 @@ from blance_tpu.plan.tensor import (
     _pipeline_warm_donating,
     _pipeline_warm_jit,
     carry_from_assignment,
-    plan_next_map_tpu,
     plan_pipeline,
     solve_dense_converged,
 )
@@ -150,8 +154,33 @@ def test_prev_from_entries_matches_encode_fill():
 
 
 # ---------------------------------------------------------------------------
-# plan_pipeline ≡ staged path
+# plan_pipeline ≡ the unfused solver
 # ---------------------------------------------------------------------------
+
+
+def _unfused(prev_map, nodes, removed, opts, favor_min_nodes=False):
+    """Reference for the fused program: the same encode, the dense
+    engine's converged solve as its own dispatch, a host read, the host
+    decode, and the host move diff of the two maps."""
+    problem = encode_problem(prev_map, prev_map, nodes, removed, M2, opts)
+    rules = tuple(tuple(problem.rules.get(si, ()))
+                  for si in range(problem.S))
+    constraints = tuple(int(c) for c in problem.constraints)
+    arrays = (problem.prev, problem.partition_weights,
+              problem.node_weights, problem.valid_node,
+              problem.stickiness, problem.gids, problem.gid_valid)
+    p_real = None
+    if opts.shape_bucketing:
+        arrays = pad_problem_arrays(*arrays, bucket_size(problem.P),
+                                    bucket_size(problem.N))
+        p_real = jax.device_put(np.float32(problem.P))
+    assign = np.asarray(solve_dense_converged(
+        *(jnp.asarray(a) for a in arrays), constraints, rules,
+        p_real=p_real))[:problem.P]
+    next_map, warnings = decode_assignment(problem, assign, prev_map,
+                                           removed)
+    return next_map, warnings, calc_all_moves(
+        prev_map, next_map, M2, favor_min_nodes=favor_min_nodes)
 
 
 @pytest.mark.parametrize("with_rules", [True, False])
@@ -159,9 +188,7 @@ def test_plan_pipeline_identical_to_staged(with_rules):
     prev_map, nodes = _mk_map(96, 12, seed=1)
     removed = [nodes[3]]
     opts = _rack_opts(nodes) if with_rules else PlanOptions()
-    smap, swarn = plan_next_map_tpu(prev_map, prev_map, nodes, removed,
-                                    [], M2, opts)
-    smoves = calc_all_moves(prev_map, smap, M2)
+    smap, swarn, smoves = _unfused(prev_map, nodes, removed, opts)
     fmap, fwarn, fmoves = plan_pipeline(prev_map, prev_map, nodes,
                                         removed, [], M2, opts)
     assert _maps_equal(smap, fmap)
@@ -172,13 +199,12 @@ def test_plan_pipeline_identical_to_staged(with_rules):
 def test_plan_pipeline_bucketed_identical_to_staged():
     prev_map, nodes = _mk_map(70, 11, seed=2)
     opts = _rack_opts(nodes, shape_bucketing=True)
-    smap, swarn = plan_next_map_tpu(prev_map, prev_map, nodes,
-                                    [nodes[1]], [], M2, opts)
+    smap, swarn, smoves = _unfused(prev_map, nodes, [nodes[1]], opts)
     fmap, fwarn, fmoves = plan_pipeline(prev_map, prev_map, nodes,
                                         [nodes[1]], [], M2, opts)
     assert _maps_equal(smap, fmap)
     assert swarn == fwarn
-    assert fmoves == calc_all_moves(prev_map, smap, M2)
+    assert fmoves == smoves
 
 
 def test_plan_pipeline_favor_min_nodes_order():
@@ -186,10 +212,9 @@ def test_plan_pipeline_favor_min_nodes_order():
     _m, _w, fmoves = plan_pipeline(prev_map, prev_map, nodes,
                                    [nodes[0]], [], M2, PlanOptions(),
                                    favor_min_nodes=True)
-    smap, _ = plan_next_map_tpu(prev_map, prev_map, nodes, [nodes[0]],
-                                [], M2, PlanOptions())
-    assert fmoves == calc_all_moves(prev_map, smap, M2,
-                                    favor_min_nodes=True)
+    *_map, smoves = _unfused(prev_map, nodes, [nodes[0]], PlanOptions(),
+                             favor_min_nodes=True)
+    assert fmoves == smoves
 
 
 def test_plan_pipeline_unsupported_opts_falls_back_exact():
@@ -199,7 +224,7 @@ def test_plan_pipeline_unsupported_opts_falls_back_exact():
     from blance_tpu.plan.api import plan_next_map
 
     smap, swarn = plan_next_map(prev_map, prev_map, nodes, [], [], M2,
-                                opts, backend="tpu")
+                                opts, backend="native")
     fmap, fwarn, fmoves = plan_pipeline(prev_map, prev_map, nodes, [],
                                         [], M2, opts)
     assert _maps_equal(smap, fmap)
@@ -208,18 +233,18 @@ def test_plan_pipeline_unsupported_opts_falls_back_exact():
 
 
 def test_plan_next_map_fused_pipeline_option():
-    """backend="tpu" + PlanOptions.fused_pipeline rides the pipeline and
-    stays bit-identical to the staged plan_next_map."""
+    """backend="tpu" returns plan_pipeline's map and warnings, bit for
+    bit."""
     from blance_tpu.plan.api import plan_next_map
 
     prev_map, nodes = _mk_map(64, 8, seed=4)
-    smap, swarn = plan_next_map(prev_map, prev_map, nodes, [nodes[2]],
+    fmap, fwarn, _moves = plan_pipeline(prev_map, prev_map, nodes,
+                                        [nodes[2]], [], M2,
+                                        _rack_opts(nodes))
+    tmap, twarn = plan_next_map(prev_map, prev_map, nodes, [nodes[2]],
                                 [], M2, _rack_opts(nodes), backend="tpu")
-    fmap, fwarn = plan_next_map(
-        prev_map, prev_map, nodes, [nodes[2]], [], M2,
-        _rack_opts(nodes, fused_pipeline=True), backend="tpu")
-    assert _maps_equal(smap, fmap)
-    assert swarn == fwarn
+    assert _maps_equal(tmap, fmap)
+    assert twarn == fwarn
 
 
 # ---------------------------------------------------------------------------

@@ -245,14 +245,23 @@ def test_fused_solve_node_removal():
     assert check_assignment(p2, a2) == CLEAN
 
 
-def test_fused_default_plumbed_through_api():
-    """set_fused_score_default routes plan_next_map_tpu through the
-    fused engine; the public result honors the same contract."""
+def test_fused_default_plumbed_through_api(monkeypatch):
+    """set_fused_score_default routes plan_next_map_tpu's pipeline
+    program through the fused engine; the public result honors the same
+    contract."""
     import warnings as w
 
     from blance_tpu import plan_next_map
     from blance_tpu.plan import tensor as T
 
+    real = T._pipeline_cold_donating
+    engines = []
+
+    def spy(*args, **kwargs):
+        engines.append(kwargs["fused_score"])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(T, "_pipeline_cold_donating", spy)
     T.set_fused_score_default("interpret")
     try:
         nodes = [f"n{i}" for i in range(8)]
@@ -268,6 +277,7 @@ def test_fused_default_plumbed_through_api():
                 empty_parts(48), empty_parts(48), nodes, [], nodes, m,
                 opts, backend="tpu")
         assert not warns
+        assert engines == ["interpret"]
         rackof = {n: i // 2 for i, n in enumerate(nodes)}
         for p in result.values():
             pr = rackof[p.nodes_by_state["primary"][0]]

@@ -49,8 +49,8 @@ from blance_tpu.plan.tensor import (
 
 
 def _dense_args(P, N, seed=0, rack=5, remove_frac=20, weights=False):
-    """Solver arrays for the rack-rule delta shape (bench.build_dense's
-    twin, plus optional heterogeneous weights)."""
+    """Solver arrays for the rack-rule delta shape, plus optional
+    heterogeneous weights."""
     rng = np.random.default_rng(seed)
     S, R = 2, 1
     prev = np.full((P, S, R), -1, np.int32)
@@ -461,6 +461,20 @@ def test_dense_memory_guard_structured_refusal():
         set_dense_score_budget(None)
     # Back under budget: no refusal.
     check_dense_memory(P, 2, N, "off")
+
+
+def test_sparse_program_peak_under_dense_projection():
+    """The sparse engine's compiled program allocates less than the
+    dense matrix engine's projected [P, N] working set — the one the
+    memory guard refuses — from the AOT memory analysis of the program
+    (abstract operands, no FLOPs run)."""
+    from blance_tpu.analysis import membudget as mb
+    from blance_tpu.analysis.shape_audit import Dims
+
+    d = Dims(P=4096, S=2, N=256, R=2, L=2)
+    peak = mb._measure_entry("sparse.cold", d,
+                             mb._builders()["sparse.cold"])
+    assert 0 < peak < projected_score_bytes(d.P, d.N)
 
 
 def test_auto_routes_to_sparse_past_budget():

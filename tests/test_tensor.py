@@ -313,11 +313,14 @@ def test_validation_gate_catches_broken_solver(monkeypatch):
 
     from blance_tpu.plan import tensor as T
 
-    def broken_solve(prev, *args, **kwargs):
-        out = np.zeros(prev.shape, np.int32)  # everyone on node 0
-        return out
+    real = T._pipeline_cold_donating
 
-    monkeypatch.setattr(T, "solve_dense_converged", broken_solve)
+    def broken_solve(*args, **kwargs):
+        assign, prices, used, host = real(*args, **kwargs)
+        # The host buffer leads with the assignment: everyone on node 0.
+        return assign, prices, used, host.at[:assign.size].set(0)
+
+    monkeypatch.setattr(T, "_pipeline_cold_donating", broken_solve)
     nodes = [f"n{i}" for i in range(4)]
     with pytest.warns(UserWarning, match="constraint-violating"):
         T.plan_next_map_tpu(
@@ -905,14 +908,13 @@ def test_hier_audit_counts_planted_miss():
 def test_engine_compile_failure_falls_back_to_fused(monkeypatch):
     """An auto-selected matrix engine that dies in compile must retry on
     the fused engine with a UserWarning and a timer annotation — never a
-    user-visible error (the production mirror of bench.py's engine
-    degradation)."""
+    user-visible error."""
     import warnings
 
     from blance_tpu.plan import tensor
     from blance_tpu.utils.trace import PhaseTimer
 
-    real = tensor.solve_dense_converged
+    real = tensor._pipeline_cold_donating
     calls = []
 
     def flaky(*args, **kwargs):
@@ -924,7 +926,7 @@ def test_engine_compile_failure_falls_back_to_fused(monkeypatch):
         kwargs["fused_score"] = "interpret"
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(tensor, "solve_dense_converged", flaky)
+    monkeypatch.setattr(tensor, "_pipeline_cold_donating", flaky)
     monkeypatch.setattr(tensor, "pallas_available", lambda: True)
 
     nodes = [f"n{i}" for i in range(8)]
@@ -956,7 +958,7 @@ def test_engine_explicit_mode_fails_loudly(monkeypatch):
     def boom(*args, **kwargs):
         raise RuntimeError("injected compile failure")
 
-    monkeypatch.setattr(tensor, "solve_dense_converged", boom)
+    monkeypatch.setattr(tensor, "_pipeline_cold_donating", boom)
     nodes = [f"n{i}" for i in range(8)]
     parts = empty_parts(32)
     tensor.set_fused_score_default("off")

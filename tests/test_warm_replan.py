@@ -350,7 +350,8 @@ def test_auto_threshold_override_routes_backend():
 
     low = spans_for(PlanOptions(auto_tpu_threshold=1))
     high = spans_for(PlanOptions(auto_tpu_threshold=10 ** 9))
-    # Low threshold: device path (encode/solve/decode phase spans).
-    assert "plan.solve" in low
+    # Low threshold: device path (encode/dispatch/decode phase spans).
+    assert "plan.pipeline.dispatch" in low
     # High threshold: exact path — no device phase spans.
-    assert "plan.solve" not in high and "plan.encode" not in high
+    assert "plan.pipeline.dispatch" not in high and \
+        "plan.encode" not in high
