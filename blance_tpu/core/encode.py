@@ -222,9 +222,11 @@ def pack_slot_rows(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     ``prev`` is bit-equal to what a fresh ``encode_problem`` of the
     decoded map would scatter."""
     mask = rows >= 0
+    counts = mask.sum(axis=-1).astype(np.int64)
+    if rows.shape[-1] <= 1:
+        return rows.copy(), counts  # one slot: already packed
     order = np.argsort(~mask, axis=-1, kind="stable")
     packed = np.take_along_axis(rows, order, axis=-1)
-    counts = mask.sum(axis=-1).astype(np.int64)
     return packed, counts
 
 

@@ -170,10 +170,13 @@ class ServicePlanner(CyclePlanner):
                 if self._resident:
                     self._rec.count("fleet.decode_full")
             else:
-                next_map, warnings, full, nrows = st.decode(
-                    np.asarray(result.assign), current, removes)
+                next_map, warnings, full, nrows, batched = st.decode(
+                    np.asarray(result.assign), current, removes,
+                    result.share)
                 self._rec.count("fleet.decode_full" if full
                                 else "fleet.decode_patch")
+                if batched:
+                    self._rec.count("fleet.decode_batched")
                 if not full:
                     self._rec.observe("fleet.decode_dirty_rows",
                                       float(nrows))

@@ -393,6 +393,12 @@ def default_registry() -> MetricsRegistry:
         Metric("fleet.decode_patch", "counter",
                "incremental decodes: held map patched at the changed "
                "rows, bit-identical to the full decode"),
+        Metric("fleet.decode_batched", "counter",
+               "incremental decodes served by the class batch's "
+               "decode share (plan/fleet.py DecodeShare): the diff, "
+               "pack, counts and shortfall rows computed once per "
+               "class, so the tenant only builds its changed rows; "
+               "each is also a fleet.decode_patch"),
         Metric("fleet.decode_dirty_rows", "histogram",
                "rows rebuilt per incremental decode (the rows the "
                "solve actually changed)"),

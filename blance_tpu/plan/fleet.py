@@ -58,6 +58,7 @@ from ..core.encode import (
     DenseProblem,
     NPArray,
     bucket_size,
+    pack_slot_rows,
     pad_problem_arrays,
     pad_to,
     stack_problem_arrays,
@@ -78,7 +79,8 @@ from .tensor import (
     resolve_fused_score,
 )
 
-__all__ = ["TenantProblem", "BatchClass", "FleetResult", "batch_class_of",
+__all__ = ["TenantProblem", "BatchClass", "FleetResult", "DecodeShare",
+           "batch_class_of",
            "validate_tenant", "tier_band_refusals", "refused_tenants",
            "solve_fleet", "FLEET_AXIS"]
 
@@ -151,6 +153,26 @@ class TenantProblem:
 
 
 @dataclass
+class DecodeShare:
+    """One tenant's share of the decode's array work, computed once per
+    class batch (:func:`_decode_shares`), so a resident decode
+    (plan/resident.py ``EncodedState.decode``) only builds the
+    ``Partition`` objects of its changed rows.  Every field diffs the
+    solve's ``assign`` against ``prev``; the modeled states are those
+    with a constraint above 0, in state order."""
+
+    prev: NPArray  # the tenant's submitted [P, S, R] prev (the object)
+    changed: list[int]  # rows whose assignment differs from prev
+    ids: list[int]  # node ids of the changed rows, flat: per changed
+    #                 row, per modeled state, R slots packed left
+    fill: list[int]  # filled slots per changed row and modeled state
+    packed: NPArray  # [P, S, R] int32: prev with the changed rows packed
+    counts: NPArray  # [P, S] int64 filled slots per row of ``packed``
+    short: list[list[int]]  # per modeled state: rows under its
+    #                         constraint ([] when no row is short)
+
+
+@dataclass
 class FleetResult:
     """One tenant's solve outcome (arrays at the REAL, unpadded shape)."""
 
@@ -160,6 +182,7 @@ class FleetResult:
     warm: bool  # solved by an accepted one-sweep repair
     sweeps: int  # converged-loop passes executed
     klass: Optional[BatchClass]  # None for degenerate (empty) problems
+    share: Optional[DecodeShare] = None  # None for degenerate problems
 
 
 def batch_class_of(t: TenantProblem) -> BatchClass:
@@ -566,6 +589,61 @@ def _real_carry(assign: NPArray, used_padded: NPArray,
     return SolveCarry(prices=used.sum(axis=0), assign=assign, used=used)
 
 
+def _decode_shares(prevs: Sequence[NPArray], prev_b: NPArray,
+                   assign_b: NPArray, constraints: tuple[int, ...],
+                   keep: Optional[NPArray] = None,
+                   ) -> list[Optional[DecodeShare]]:
+    """The decode's array half for a whole class batch, vectorized over
+    its tenants: the changed-row diff (pad rows masked out), one
+    :func:`~blance_tpu.core.encode.pack_slot_rows` over every changed
+    row, the counts, the shortfall rows and one flat ``tolist`` (flat:
+    a nested list per row would cost an object per row and state, and
+    the collector's passes over them).  ``prev_b``
+    and ``assign_b`` are the batch's padded ``[B, P, S, R]`` prev and
+    solve; ``prevs`` the tenants' own ``prev`` arrays.  Tenants that
+    ``keep`` marks False (declined warm repairs) get None.
+
+    ``counts`` covers every row of ``packed``; a row the solve left
+    alone keeps prev's fill, which is what a resident state's counts
+    hold for it (plan/resident.py)."""
+    b, p = assign_b.shape[:2]
+    p_real = [pv.shape[0] for pv in prevs]
+    kept = [True] * b if keep is None else np.asarray(keep, bool).tolist()
+    real = (np.arange(p) < np.asarray(p_real)[:, None]) \
+        & np.asarray(kept)[:, None]  # [B, P]
+    changed = (assign_b != prev_b).any(axis=(2, 3)) & real
+    bi, pi = np.nonzero(changed)
+    rows_c, counts_c = pack_slot_rows(
+        assign_b[bi, pi].astype(np.int32, copy=False))
+    packed_b = prev_b.copy()
+    packed_b[bi, pi] = rows_c
+    counts_b = (packed_b >= 0).sum(axis=3).astype(np.int64)
+    mod = [si for si, c in enumerate(constraints) if c > 0]
+    want = np.asarray([constraints[si] for si in mod], np.int64)
+    short_b = (counts_b[:, :, mod] < want) & real[:, :, None]
+    any_short = short_b.any(axis=(1, 2)).tolist()
+    ids = rows_c[:, mod, :].ravel().tolist()
+    fill = counts_c[:, mod].ravel().tolist()
+    m_r, m_n = len(mod) * rows_c.shape[2], len(mod)
+    bounds = np.searchsorted(bi, np.arange(b + 1)).tolist()
+    changed_rows = pi.tolist()
+    out: list[Optional[DecodeShare]] = []
+    for j, (lo, hi, pr, ok, short_any) in enumerate(zip(
+            bounds, bounds[1:], p_real, kept, any_short)):
+        if not ok:
+            out.append(None)
+            continue
+        short = [np.flatnonzero(short_b[j, :, m]).tolist()
+                 for m in range(m_n)] if short_any else []
+        # Copies off the batch tensors, as assign's: a view would pin
+        # the whole batch alive per tenant.
+        out.append(DecodeShare(
+            prevs[j], changed_rows[lo:hi], ids[lo * m_r:hi * m_r],
+            fill[lo * m_n:hi * m_n], packed_b[j, :pr].copy(),
+            counts_b[j, :pr].copy(), short))
+    return out
+
+
 def _trace_attrs(trace_ids: Optional[dict[str, str]],
                  keys: Sequence[str]) -> dict[str, str]:
     """Span attrs carrying the batch members' trace ids (capped: a
@@ -624,7 +702,10 @@ def solve_fleet(
     back to the host, counted by ``fleet.d2h_waits``) inside it, all
     three also host annotations in a profiler trace, and the
     ``fleet.dispatch_s`` histogram; per-tenant ``plan.solve.*``
-    carry/sweep counters mirror the single-problem spellings.
+    carry/sweep counters mirror the single-problem spellings.  Each
+    solved result carries its :class:`DecodeShare`, computed for the
+    whole class batch inside its ``fleet.dispatch`` span, after the
+    reads.
     ``recorder`` overrides the process recorder (the plan service
     passes its own so executor-thread solves report to the right one).
     ``trace_ids`` (tenant key → trace id, the plan service's
@@ -723,6 +804,9 @@ def _solve_checked(
                 out_b, used_b, ok_b = _dispatch(
                     stacked, mesh, True, k, max_iterations, mode, rec,
                     record, batch_floor=batch_floor)
+                shares = _decode_shares(
+                    [tenants[i].prev for i in warm_idx], stacked[0],
+                    out_b, k.constraints, keep=ok_b)
             if record:
                 rec.observe("fleet.dispatch_s", rec.now() - t0)
                 rec.count("fleet.batches")
@@ -742,7 +826,7 @@ def _solve_checked(
                     results[i] = FleetResult(
                         key=t.key, assign=assign,
                         carry=_real_carry(assign, used_b[j], n_real),
-                        warm=True, sweeps=1, klass=k)
+                        warm=True, sweeps=1, klass=k, share=shares[j])
                 else:
                     # Declined repair: same accounting as
                     # solve_dense_warm's decline, then the cold batch
@@ -770,6 +854,9 @@ def _solve_checked(
                 out_b, sweeps_b, used_b = _dispatch(
                     stacked, mesh, False, k, max_iterations, mode, rec,
                     record, batch_floor=batch_floor)
+                shares = _decode_shares(
+                    [tenants[i].prev for i in cold_idx], stacked[0],
+                    out_b, k.constraints)
             if record:
                 rec.observe("fleet.dispatch_s", rec.now() - t0)
                 rec.count("fleet.batches")
@@ -783,6 +870,7 @@ def _solve_checked(
                 results[i] = FleetResult(
                     key=t.key, assign=assign,
                     carry=_real_carry(assign, used_b[j], n_real),
-                    warm=False, sweeps=int(sweeps_b[j]), klass=k)
+                    warm=False, sweeps=int(sweeps_b[j]), klass=k,
+                    share=shares[j])
 
     return [results[i] for i in range(len(tenants))]

@@ -36,7 +36,10 @@ problem resident too:
   rows (same ``Partition`` row spelling as ``decode_assignment``'s
   fast branch) and shortfall warnings regenerate from the resident
   ``counts``; the full ``decode_assignment`` runs only on a cold
-  cycle's first decode.
+  cycle's first decode.  The fleet tier does the array half of that
+  (diff, pack, counts, shortfall rows) once per class batch
+  (``plan/fleet.py`` ``DecodeShare``), so a tenant whose ``prev`` is
+  the one the batch diffed against only builds its changed rows.
 
 The CONSERVATIVE protocol (the ServicePlanner side lives in
 ``blance_tpu/fleetloop.py``): warm state is keyed to the *identity* of
@@ -51,7 +54,7 @@ arrays bit-equal to that re-encode across every delta family.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import TYPE_CHECKING, Any, Optional
 
 import numpy as np
 
@@ -69,6 +72,9 @@ from ..core.types import (
     PartitionModel,
     PlanOptions,
 )
+
+if TYPE_CHECKING:  # annotation-only: keep jax imports lazy at runtime
+    from .fleet import DecodeShare
 
 __all__ = ["EncodedState", "Proposal", "build_encoded_state"]
 
@@ -380,16 +386,26 @@ class EncodedState:
     # -- incremental decode --------------------------------------------------
 
     def decode(self, assign: NPArray, current: PartitionMap,
-               removes: list[str]) -> tuple[
-                   PartitionMap, dict[str, list[str]], bool, int]:
+               removes: list[str],
+               share: Optional["DecodeShare"] = None) -> tuple[
+                   PartitionMap, dict[str, list[str]], bool, int, bool]:
         """Decode a solve against the resident state: patch the held
         map at the changed rows (full ``decode_assignment`` only when
         no canonical held map exists yet), regenerate shortfall
         warnings from the resident counts, and stage the proposal for
-        adoption.  Returns (map, warnings, was_full_decode, changed
-        rows).  Bit-identity to the full decode is pinned by
+        adoption.  ``share`` is the result's class-batch
+        :class:`~blance_tpu.plan.fleet.DecodeShare`: when it was
+        computed against the very ``prev`` object held here, its diff,
+        pack, counts and shortfall rows stand in for this tenant's own
+        and only the changed rows' ``Partition`` objects are built.
+        Returns (map, warnings, was_full_decode, changed rows,
+        decoded_from_share).  Bit-identity to the full decode, with
+        and without a share, is pinned by
         tests/test_encode_resident.py."""
         pr = self.problem
+        if self.map is not None and share is not None \
+                and share.prev is pr.prev:
+            return self._decode_share(share, self.map)
         prev = pr.prev
         changed_idx = np.flatnonzero(
             (assign != prev).any(axis=(1, 2)))
@@ -435,7 +451,41 @@ class EncodedState:
         self.pending = Proposal(
             map=next_map, packed=packed, counts=counts_new,
             changed=[pr.partitions[i] for i in changed_idx.tolist()])
-        return next_map, warnings, full, int(changed_idx.size)
+        return next_map, warnings, full, int(changed_idx.size), False
+
+    def _decode_share(self, share: "DecodeShare",
+                      held: PartitionMap) -> tuple[
+                          PartitionMap, dict[str, list[str]], bool, int,
+                          bool]:
+        """The incremental decode off a class-batch share: the same
+        rows, warnings and proposal as :meth:`decode`'s own array path
+        (the share's counts equal the resident ``counts`` on every row
+        the solve left alone: both are prev's fill)."""
+        pr = self.problem
+        nodes = pr.nodes
+        parts = pr.partitions
+        ids, fill, r = share.ids, share.fill, pr.R
+        next_map = dict(held)
+        k = 0  # (changed row, modeled state) ordinal
+        for pi in share.changed:
+            pname = parts[pi]
+            row: dict[str, list[str]] = {}
+            for _si, sname in self.mod:
+                lo = k * r
+                row[sname] = [nodes[i] for i in ids[lo:lo + fill[k]]]
+                k += 1
+            next_map[pname] = Partition(pname, row)
+        warnings: dict[str, list[str]] = {}
+        for (si, sname), short in zip(self.mod, share.short):
+            want = int(pr.constraints[si])
+            for pi in short:
+                pname = parts[pi]
+                warnings.setdefault(pname, []).append(
+                    _WARN_FMT % (want, sname, pname))
+        changed = [parts[i] for i in share.changed]
+        self.pending = Proposal(map=next_map, packed=share.packed,
+                                counts=share.counts, changed=changed)
+        return next_map, warnings, False, len(changed), True
 
 
 def build_encoded_state(

@@ -141,6 +141,7 @@ class FleetSimReport:
     encode_patch_rows: int = 0
     decode_full: int = 0
     decode_patch: int = 0
+    decode_batched: int = 0
     steps: int = 0
     wall_s: float = 0.0  # host time; NOT part of the replayable account
     # Host wall-clock split of the cycle cost (encode / decode /
@@ -361,6 +362,7 @@ async def _fleet_main(scn: FleetScenario, loop: DeterministicLoop,
             "fleet.encode_patch_rows", (0, 0.0))[1]),  # exact sum
         decode_full=int(rec.counters.get("fleet.decode_full", 0)),
         decode_patch=int(rec.counters.get("fleet.decode_patch", 0)),
+        decode_batched=int(rec.counters.get("fleet.decode_batched", 0)),
         phase_wall=phase_wall)
 
 

@@ -37,7 +37,10 @@ from blance_tpu.core.types import (
 )
 from blance_tpu.fleetloop import FleetController, ServicePlanner
 from blance_tpu.obs import Recorder, use_recorder
+import blance_tpu.plan.fleet as fleet_mod
 from blance_tpu.plan.carry import EncodeCache
+from blance_tpu.plan.fleet import TenantProblem, solve_fleet
+from blance_tpu.plan.resident import build_encoded_state
 from blance_tpu.plan.service import PlanService
 from blance_tpu.rebalance import ClusterDelta, _strip_nodes
 from blance_tpu.testing.fleetsim import run_fleet_scenario, tenant_model
@@ -344,6 +347,112 @@ def test_incremental_decode_warnings_bit_identical():
         await base.svc.stop()
 
     _run(loop, rec, drive())
+
+
+# -- class-batch decode shares (plan/fleet.py DecodeShare) -------------------
+
+
+def _state_pair(parts):
+    """Two resident states of one tenant over the same map: the first
+    decodes from the class share, its twin by its own array path."""
+    names, pmap = _cluster(parts=parts)
+    opts = PlanOptions()
+    return [build_encoded_state(
+        encode_problem(pmap, pmap, names, [], M, opts), pmap, [], M, opts)
+        for _ in range(2)]
+
+
+def _dirty_rows(st, dark):
+    """The planner's delta mask: the rows holding a dark node."""
+    ids = [st.node_index[n] for n in dark]
+    return np.isin(st.problem.prev, ids).any(axis=(1, 2))
+
+
+# Each case: the tenants' partition counts (12 rides the module's shared
+# 12/12 class; 17 and 18 share the 18/12 class, where 17 has a pad row),
+# then per cycle the removed nodes, whether to run warm off the last
+# carries, and which tenants' dirty masks lie (empty) so their repair
+# ripples and is declined into the class's cold batch.
+_SHARE_CASES = {
+    "pad_rows": ((12, 17, 18), [([], False, ()), (["n3"], False, ()),
+                                (["n3", "n7"], False, ())]),
+    "shortfall": ((12, 12), [([], False, ()),
+                             ([f"n{i}" for i in range(11)], False, ()),
+                             ([f"n{i}" for i in range(11)], False, ())]),
+    "declined_warm": ((12, 12), [([], False, ()), (["n5"], True, (1,))]),
+    "adopt_cycles": ((12, 12, 12), [([], False, ()), (["n1"], True, ()),
+                                    (["n1", "n4"], True, ()),
+                                    (["n4"], False, ()),
+                                    (["n4", "n9"], True, ())]),
+    "stale_share": ((12, 17), [([], False, ()), (["n2"], False, ()),
+                               (["n2", "n6"], False, ())]),
+}
+
+
+@pytest.mark.parametrize("case", list(_SHARE_CASES))
+def test_class_share_decode_equals_own_decode(case, monkeypatch):
+    """The class batch's decode share (diff, pack, counts and shortfall
+    rows computed once per class, vectorized) decodes every tenant
+    bit-identically to its own per-tenant array path: the same map in
+    the same order, the same warnings in their order, and the same
+    staged Proposal (``packed``, ``counts``, ``changed``).  A share not
+    computed against the held ``prev`` (``stale_share``) takes the
+    tenant's own path and still gives the same answer."""
+    sizes, cycles = _SHARE_CASES[case]
+    if case == "declined_warm":
+        # Bypass the host precheck so the batched repair itself sees
+        # the lying mask's ripple and declines (tests/test_fleet.py).
+        monkeypatch.setattr(fleet_mod, "capacity_shrank",
+                            lambda *a, **k: False)
+    pairs = [_state_pair(p) for p in sizes]
+    rec = Recorder()
+    results = None
+    for step, (dark, warm, lying) in enumerate(cycles):
+        problems = []
+        for j, (a, b) in enumerate(pairs):
+            for st in (a, b):
+                st.apply_removes(frozenset(dark))
+            carry = dirty = None
+            if warm:
+                carry = results[j].carry
+                dirty = _dirty_rows(a, dark)
+                if j in lying:
+                    dirty = np.zeros_like(dirty)
+            problems.append(TenantProblem.from_dense(
+                f"t{j}", a.problem, carry=carry, dirty=dirty))
+        with use_recorder(rec):
+            results = solve_fleet(problems, batch_floor=16, recorder=rec)
+        for j, ((a, b), r) in enumerate(zip(pairs, results)):
+            ctx = f"{case} step={step} tenant={j}"
+            share = r.share
+            assert share is not None and share.prev is problems[j].prev
+            stale = case == "stale_share" and step == len(cycles) - 1
+            if stale:
+                share = dataclasses.replace(share, prev=share.prev.copy())
+            held = a.map is not None
+            ma, wa, fa, na, ba = a.decode(r.assign, a.expected, dark,
+                                          share)
+            mb, wb, fb, nb, bb = b.decode(r.assign, b.expected, dark)
+            assert ba == (held and not stale) and not bb, ctx
+            assert (fa, na) == (fb, nb) and fa == (not held), ctx
+            assert list(ma) == list(mb) and _nbs(ma) == _nbs(mb), ctx
+            assert wa == wb and list(wa) == list(wb), ctx
+            pa, pb = a.pending, b.pending
+            assert pa.changed == pb.changed, ctx
+            for f in ("packed", "counts"):
+                x, y = getattr(pa, f), getattr(pb, f)
+                assert x.dtype == y.dtype and x.shape == y.shape \
+                    and np.array_equal(x, y), f"{ctx}: {f}"
+            for st in (a, b):
+                st.adopt(st.pending, st.pending.map)
+        if case == "shortfall" and step:
+            assert all(r.share.short for r in results)
+    assert all(a.map is not None for a, _b in pairs)
+    if case == "declined_warm":
+        assert rec.counters.get("plan.solve.warm_fallback", 0) >= 1
+        assert not results[1].warm
+    if case == "adopt_cycles":
+        assert rec.counters.get("plan.solve.carry_hit", 0) > 0
 
 
 # -- demotion paths -----------------------------------------------------------

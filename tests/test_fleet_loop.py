@@ -341,6 +341,31 @@ def test_shared_cache_eviction_under_fleet_only_costs_cold():
     assert a.fleet.moves_executed == b.fleet.moves_executed
 
 
+def test_warm_decodes_count_the_class_share(monkeypatch):
+    """Over warm FleetController cycles every incremental decode is
+    served by its class batch's decode share: ``fleet.decode_batched``
+    equals the tenant decodes that took a share computed against the
+    held prev, each is still a ``fleet.decode_patch``, and
+    ``decode_patch == encode_warm`` holds as before."""
+    from blance_tpu.plan.resident import EncodedState
+
+    took: list[str] = []
+    own = EncodedState.decode
+
+    def spy(self, assign, current, removes, share=None):
+        if share is not None and self.map is not None \
+                and share.prev is self.problem.prev:
+            took.append("share")
+        return own(self, assign, current, removes, share)
+
+    monkeypatch.setattr(EncodedState, "decode", spy)
+    res = run_fleet_scenario(fleet_zone_outage(seed=5, tenants=6))
+    assert res.complete
+    assert res.encode_warm > 0
+    assert res.decode_batched == len(took) > 0
+    assert res.decode_batched == res.decode_patch == res.encode_warm
+
+
 def test_planner_rejects_scoring_hooks():
     from blance_tpu.core.types import PlanOptions
 
